@@ -1,9 +1,13 @@
 """§Roofline: per (arch x shape x mesh) three-term roofline from the
 dry-run artifacts.
 
-    compute term    = HLO_FLOPs / (chips x 197 TFLOP/s)
-    memory term     = HLO_bytes / (chips x 819 GB/s)
-    collective term = collective_bytes / (chips x 50 GB/s link)
+    compute term    = HLO_FLOPs / (chips x peak bf16 FLOP/s)
+    memory term     = HLO_bytes / (chips x peak HBM bytes/s)
+    collective term = collective_bytes / (chips x peak ICI bytes/s)
+
+The peaks are those of the record's ``device_kind`` (the chip the
+dry-run meshes model), from ``repro.launch.mesh.CHIP_PEAKS``; a kind
+without published peaks is an error.
 
 HLO_FLOPs / HLO_bytes / collective_bytes come from the trip-count-aware
 HLO walker (utils/hlo.py) over the compiled module — per-device numbers,
@@ -29,7 +33,7 @@ from pathlib import Path
 import jax
 
 from repro.configs import ASSIGNED, SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 ART = Path(__file__).resolve().parent.parent / "artifacts" / "dryrun"
 OUT = Path(__file__).resolve().parent.parent / "artifacts" / "benchmarks"
@@ -81,9 +85,10 @@ def analyze(record: dict) -> dict:
     prof = record["profile"]
     arch, shape, mesh = record["arch"], record["shape"], record["mesh"]
     chips = record["chips"]
-    t_compute = prof["flops"] / PEAK_FLOPS_BF16
-    t_memory = prof["bytes_accessed"] / HBM_BW
-    t_coll = prof["collective_bytes"] / ICI_BW
+    peaks = chip_peaks(record.get("device_kind"))
+    t_compute = prof["flops"] / peaks["flops_bf16"]
+    t_memory = prof["bytes_accessed"] / peaks["hbm_bw"]
+    t_coll = prof["collective_bytes"] / peaks["ici_bw"]
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
@@ -96,7 +101,7 @@ def analyze(record: dict) -> dict:
         "dominant": dominant,
         "model_flops_per_chip": mf,
         "useful_flops_ratio": mf / max(prof["flops"], 1.0),
-        "roofline_fraction": (mf / PEAK_FLOPS_BF16) / max(bound, 1e-12),
+        "roofline_fraction": mf / peaks["flops_bf16"] / max(bound, 1e-12),
         "lever": LEVERS[dominant],
     }
 

@@ -134,4 +134,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

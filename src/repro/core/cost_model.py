@@ -18,8 +18,8 @@ Evaluator tiers (all built from ONE closure, ``_make_batch_tpd``):
   the scalar left-to-right accumulation; at width >= 8 numpy switches to
   unrolled partial sums and agreement drops to ~1e-15 relative).
 * ``batch_tpd`` — whole-swarm (P, D) -> (P,) evaluation; numpy fast path
-  below ``_NP_FASTPATH_ELEMS``, jit'd XLA above, and on TPU backends a
-  Pallas kernel (``repro.kernels.tpd``) for large batches.
+  below ``_NP_FASTPATH_ELEMS``; above it the compiled Pallas kernel
+  (``repro.kernels.tpd``) on TPU, jit'd XLA on every other backend.
 * ``PooledTPDEvaluator`` — S same-shape cost models with independent
   client pools evaluated in ONE exact call (the batched sweep runner's
   engine: placement row i scores against pool ``pool_idx[i]``).
@@ -419,12 +419,11 @@ class CostModel:
 
     def _pallas_ok(self) -> bool:
         """The Pallas TPD kernel covers the base eq. 6/7 model (no pod
-        edge costs, no trace-calibrated terms) and compiles on TPU and
-        GPU backends (tiled per backend — see
-        ``kernels.tpd.default_block_p``)."""
+        edge costs, no trace-calibrated terms) and is compiled for the
+        TPU only."""
         return getattr(self, "pod_of", None) is None and \
             self._calibration_terms() == (1.0, (), 0.0) and \
-            jax.default_backend() in ("tpu", "gpu")
+            jax.default_backend() == "tpu"
 
     def set_default_backend(self, backend: Optional[str]) -> None:
         """Pin what ``batch_tpd(backend=None)`` dispatches to — the
@@ -437,29 +436,36 @@ class CostModel:
                              f"'interpret'")
         object.__setattr__(self, "_default_backend", backend)
 
+    def tpd_path(self, n_rows: int) -> str:
+        """The path ``batch_tpd(backend=None)`` takes for ``n_rows``
+        placements: the ``set_default_backend`` pin if any, else numpy
+        up to ``_NP_FASTPATH_ELEMS`` placement-client elements, else the
+        Pallas kernel where :meth:`_pallas_ok`, else jit'd XLA."""
+        pinned = getattr(self, "_default_backend", None)
+        if pinned is not None:
+            return pinned
+        if n_rows * self.hierarchy.total_clients <= self._NP_FASTPATH_ELEMS:
+            return "np"
+        return "pallas" if self._pallas_ok() else "jit"
+
     def batch_tpd(self, placements, backend: Optional[str] = None
                   ) -> np.ndarray:
         """(P, D) placements -> (P,) TPDs.
 
         ``backend``: ``None`` auto-selects (numpy below the fast-path
-        threshold, the Pallas kernel on TPU/GPU for large batches,
-        jit'd XLA otherwise); ``"np"`` / ``"jit"`` / ``"pallas"`` /
+        threshold, the Pallas kernel on TPU for large batches, jit'd
+        XLA otherwise); ``"np"`` / ``"jit"`` / ``"pallas"`` /
         ``"interpret"`` force a path. ``"pallas"`` compiles the kernel
-        on TPU/GPU and interprets elsewhere; ``"interpret"`` forces the
-        Pallas INTERPRETER even on accelerator backends — the CI
-        escape hatch that exercises the kernel body on any host
-        (pinned against ``kernels.ref.tpd_ref`` by the parity suite).
+        on TPU and interprets elsewhere; ``"interpret"`` forces the
+        Pallas INTERPRETER even on the TPU — the CI escape hatch that
+        exercises the kernel body on any host (pinned against
+        ``kernels.ref.tpd_ref`` by the parity suite).
         A ``set_default_backend`` pin (EvalConfig plumbing) replaces
         the auto-selection, never an explicit ``backend=``.
         """
         placements = np.asarray(placements, np.int32)
         if backend is None:
-            backend = getattr(self, "_default_backend", None)
-        if backend is None:
-            small = placements.size // max(self.hierarchy.dimensions, 1) \
-                * self.hierarchy.total_clients <= self._NP_FASTPATH_ELEMS
-            backend = "np" if small else \
-                ("pallas" if self._pallas_ok() else "jit")
+            backend = self.tpd_path(placements.shape[0])
         if backend == "np":
             fn = self._cached("_batch_tpd_np",
                               lambda: self._make_batch_tpd(np))
@@ -489,28 +495,20 @@ class CostModel:
         return fn(placements)
 
     def _make_pallas_tpd(self, force_interpret: bool = False):
-        """Closure running the fused Pallas TPD kernel: static tables are
-        baked once; per call only the (P, L) leaf loads are computed
-        host-side (the trainer-split rank trick) before the kernel fuses
-        the attribute gathers and the per-level max-reduce.
+        """Closure running the fused Pallas TPD kernel: per call only the
+        (P, L) leaf loads are computed host-side (the trainer-split rank
+        trick) before the kernel fuses the kid-payload sums, eq. 6 and
+        the per-level max-reduce.
 
-        The particle-tile size follows the backend (wide tiles on GPU,
-        the lane-sized TPU default otherwise); ``force_interpret`` runs
-        the kernel body under the Pallas interpreter regardless of
-        backend (the ``backend="interpret"`` escape hatch).
+        The kernel is compiled on TPU and interpreted on every other
+        backend; ``force_interpret`` interprets it on the TPU too (the
+        ``backend="interpret"`` escape hatch).
         """
-        from repro.kernels.tpd import (
-            batch_tpd_pallas,
-            default_block_p,
-            tpd_kernel_inputs,
-        )
+        from repro.kernels.tpd import batch_tpd_pallas
         h = self.hierarchy
-        tables = tpd_kernel_inputs(h)
         attrs = self._attr_stack(np.float32)        # (3, C)
         n_leaves, C = h.n_leaves, h.total_clients
-        jax_backend = jax.default_backend()
-        interpret = force_interpret or jax_backend not in ("tpu", "gpu")
-        block_p = default_block_p(None if interpret else jax_backend)
+        interpret = force_interpret or jax.default_backend() != "tpu"
         penalty = float(self.memory_penalty)
 
         def run(placements):
@@ -527,8 +525,9 @@ class CostModel:
                 minlength=P * n_leaves).reshape(P, n_leaves)
             out = batch_tpd_pallas(
                 jnp.asarray(placements), jnp.asarray(attrs),
-                jnp.asarray(leaf_load.astype(np.float32)), *tables,
-                penalty=penalty, block_p=block_p, interpret=interpret)
+                jnp.asarray(leaf_load.astype(np.float32)),
+                depth=h.depth, width=h.width, penalty=penalty,
+                interpret=interpret)
             return np.asarray(out)
 
         return run
@@ -590,7 +589,7 @@ class PooledTPDEvaluator:
     bit-identity pin — and splits each call's placement rows across
     devices when more than one is visible (``shard_map`` row shards +
     segment-sum merge via ``fl.distributed.shard_rows``, float64 under
-    ``jax.experimental.enable_x64``); ``"off"`` pins the numpy path
+    ``jax.enable_x64``); ``"off"`` pins the numpy path
     unconditionally; ``"on"`` forces the sharded build even on 1
     device (tests). The sharded build re-jits whenever any pool's
     version moves (closure-baked attribute stack), so it pays off on
@@ -648,14 +647,10 @@ class PooledTPDEvaluator:
     def tpds(self, placements, pool_idx=None) -> np.ndarray:
         placements = np.asarray(placements, np.int32)
         if self.shard != "off":
-            try:
-                ndev = jax.local_device_count()
-            except RuntimeError:  # pragma: no cover - no backend at all
-                ndev = 1
+            ndev = jax.local_device_count()
             if self.shard == "on" or \
                     (ndev > 1 and placements.shape[0] >= ndev):
-                return self._tpds_sharded(placements, pool_idx,
-                                          max(ndev, 1))
+                return self._tpds_sharded(placements, pool_idx, ndev)
         versions = tuple(m._client_token() for m in self.models)
         if self._fn is None or versions != self._versions:
             self._check_aligned()
@@ -674,7 +669,7 @@ class PooledTPDEvaluator:
         shard_rows`` — each device scores its shard through the same
         jit'd pooled closure and the full (P,) vector is reassembled by
         a segment-sum + psum merge. Runs in float64 under
-        ``jax.experimental.enable_x64``; numerically it is the XLA
+        ``jax.enable_x64``; numerically it is the XLA
         build of the numpy exact path (same reduction ORDER per row —
         sliced per-level maxima summed deepest-first — so any deltas
         are non-associativity noise at f64, pinned ~1e-12 by the parity
@@ -684,26 +679,33 @@ class PooledTPDEvaluator:
             placements, pool_idx,
             jax.local_device_count() if ndev is None else int(ndev))
 
-    def _tpds_sharded(self, placements, pool_idx, ndev: int) -> np.ndarray:
-        from jax.experimental import enable_x64
-
+    def sharded_fn(self, mesh, n_rows: int):
+        """The row-sharded float64 pooled evaluator over ``mesh``'s
+        ``"rows"`` axis for ``n_rows`` placement rows: ``(placements,
+        pool_idx) -> (n_rows,)``. Build and call it under
+        ``jax.enable_x64(True)``."""
         from repro.fl.distributed import shard_rows
+        self._check_aligned()
+        attrs = np.stack([m._attr_stack(np.float64) for m in self.models],
+                         axis=1)
+        fn = self.models[0]._make_batch_tpd(jnp, dtype=np.float64,
+                                            pool_attrs=attrs)
+        return shard_rows(fn, mesh, n_rows)
+
+    def _tpds_sharded(self, placements, pool_idx, ndev: int) -> np.ndarray:
+        from repro.launch.mesh import make_mesh
         n_rows = placements.shape[0]
         rows = np.arange(n_rows) if pool_idx is None \
             else np.asarray(pool_idx)
         ndev = max(1, min(int(ndev), n_rows))
         versions = tuple(m._client_token() for m in self.models)
         sig = (versions, n_rows, placements.shape[1], ndev)
-        with enable_x64():
+        with jax.enable_x64(True):
             if self._shard_fn is None or self._shard_sig != sig:
-                self._check_aligned()
-                attrs = np.stack(
-                    [m._attr_stack(np.float64) for m in self.models],
-                    axis=1)
-                fn = self.models[0]._make_batch_tpd(
-                    jnp, dtype=np.float64, pool_attrs=attrs)
-                mesh = jax.make_mesh((ndev,), ("rows",))
-                self._shard_fn = shard_rows(fn, mesh, n_rows)
+                self._shard_fn = self.sharded_fn(
+                    make_mesh((ndev,), ("rows",),
+                              devices=jax.local_devices()[:ndev]),
+                    n_rows)
                 self._shard_sig = sig
             out = self._shard_fn(jnp.asarray(placements),
                                  jnp.asarray(rows))

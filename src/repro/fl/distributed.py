@@ -31,7 +31,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.hierarchy import Hierarchy
 from repro.fl.aggregation import AggregationPlan, flat_psum, hierarchical_psum
-from repro.kernels import compat
 from repro.models.api import Model
 
 
@@ -161,7 +160,7 @@ class FLTrainStep:
             # on current JAX, but on 0.4.x it lowers axis_index to a
             # PartitionId op the CPU SPMD partitioner rejects.
             specs = self.stacked_param_pspecs()
-            return compat.shard_map(
+            return jax.shard_map(
                 agg_body, mesh=mesh,
                 in_specs=(specs,), out_specs=specs,
                 axis_names=set(mesh.axis_names), check_vma=False,
@@ -274,7 +273,7 @@ def shard_rows(fn, mesh, n_rows: int, axis: str = "rows"):
         seg = jax.ops.segment_sum(vals, idx, num_segments=total)
         return jax.lax.psum(seg, axis)
 
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=P(axis), out_specs=P(),
         axis_names={axis}, check_vma=False)

@@ -69,32 +69,39 @@ def rglru_scan_ref(a: jnp.ndarray, u: jnp.ndarray,
     return hs.swapaxes(0, 1).astype(a.dtype)
 
 
-def tpd_ref(placements, attrs, leaf_load, kids, kids_valid, is_leaf,
-            slot_leaf_idx, level_onehot, penalty: float = 0.0
-            ) -> jnp.ndarray:
+def tpd_ref(placements, attrs, leaf_load, *, depth: int, width: int,
+            penalty: float = 0.0) -> jnp.ndarray:
     """Dense-jnp oracle for the batched TPD kernel (same operands).
 
-    placements (P, D) int32; attrs (3, C) = [mdatasize, pspeed, memcap];
-    leaf_load (P, L) trainer loads per leaf aggregator; static tables
-    from ``kernels.tpd.tpd_kernel_inputs`` -> (P,) TPDs in f32.
+    placements (P, D) int32 over the complete ``width``-ary tree of
+    ``depth`` levels in heap order; attrs (3, C) = [mdatasize, pspeed,
+    memcap]; leaf_load (P, L) trainer loads per leaf aggregator ->
+    (P,) TPDs in f32. Kid payloads are gathered through the explicit
+    kid-slot table and summed in kid order.
     """
     mds, pspeed, memcap = (a.astype(jnp.float32) for a in attrs)
+    D = placements.shape[1]
+    leaf_start = D - width ** (depth - 1)
     host_mds = mds[placements]                       # (P, D)
-    kid_host = placements[:, kids]                   # (P, D, W)
-    kid_mds = mds[kid_host] * kids_valid[None]
-    child = jnp.sum(kid_mds, axis=2)
-    leaf_child = leaf_load.astype(jnp.float32)[:, slot_leaf_idx]
-    load = host_mds + jnp.where(is_leaf[None] > 0, leaf_child, child)
+    kid_slots = 1 + width * jnp.arange(leaf_start)[:, None] \
+        + jnp.arange(width)[None]                    # (D - L, W)
+    kid_mds = host_mds[:, kid_slots]                 # (P, D - L, W)
+    child = kid_mds[..., 0]
+    for w in range(1, width):
+        child = child + kid_mds[..., w]
+    child = jnp.concatenate([child, leaf_load.astype(jnp.float32)], axis=1)
+    load = host_mds + child
     delay = load / pspeed[placements]
     if penalty > 0:
         cap = memcap[placements]
         over = jnp.maximum(0.0, load - cap)
         delay = delay * (1.0 + penalty * over / jnp.maximum(cap, 1e-9))
-    masked = jnp.where(level_onehot[:, None, :] > 0, delay[None], -jnp.inf)
-    level_max = jnp.max(masked, axis=2)              # (depth, P)
     total = jnp.zeros(placements.shape[:1], jnp.float32)
-    for lv in range(level_onehot.shape[0] - 1, -1, -1):
-        total = total + level_max[lv]  # deepest first, like the kernel
+    start = D
+    for lv in range(depth - 1, -1, -1):              # deepest first
+        n = width ** lv
+        total = total + jnp.max(delay[:, start - n:start], axis=1)
+        start -= n
     return total
 
 

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 DEFAULT_BLOCK_T = 256
 DEFAULT_BLOCK_D = 256
 
@@ -103,7 +101,7 @@ def rglru_scan_pallas(a: jnp.ndarray, u: jnp.ndarray,
                                lambda ib, idd, it: (ib, it, idd)),
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         scratch_shapes=[pltpu.VMEM((block_d,), jnp.float32)],  # state carry
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, u)

@@ -1,151 +1,123 @@
 """Pallas kernel: batched TPD evaluation (paper eqs. 6-7) over a
-placement swarm, tiled per backend (TPU lanes or GPU blocks).
+placement swarm, tiled for the TPU.
 
 The swarm evaluator's hot inner shape is ``(P, D)`` placements against a
-``(3, C)`` client-attribute table: gather every slot host's attributes,
-gather every child slot's payload, reduce child payloads per slot, then
-max-reduce per tree level and sum the level maxima. On TPU the XLA
-lowering materializes each intermediate in HBM; this kernel keeps one
-``(BP, D)`` particle tile plus the whole attribute table resident in
-VMEM (C = 10k clients is 120 KiB at f32 — far under the ~16 MiB budget)
-and fuses gather -> eq. 6 delay -> per-level segment max -> level sum
-into a single pass per tile.
+``(3, C)`` client-attribute table. The hierarchy is a complete
+``width``-ary tree in heap order (``Hierarchy.level_starts``): the kids
+of slot ``s`` are slots ``width * s + 1 .. width * s + width``, so level
+``l`` is the contiguous slot range ``[a_l, a_l + width**l)`` and the
+``w``-th kid of every slot of level ``l`` is the stride-``width`` row
+range starting at ``a_{l+1} + w``. The kernel therefore needs no dynamic
+gather at all:
 
-The trainer-split leaf loads (a rank-among-unplaced scatter, awkward on
-the VPU) are computed host-side by ``CostModel._make_pallas_tpd`` with
-the same bincount trick the numpy evaluator uses, and stream in as a
-``(BP, L)`` operand.
+* the jitted wrapper gathers every slot host's attributes once
+  (``attrs[:, placements]``, one XLA gather) and lays them out
+  slot-major, ``(A, D, P)``: slots on sublanes, particles on lanes;
+* each grid step takes one 128-particle lane tile, sums every slot's
+  kid payloads with ``width`` strided sublane loads, adds the trainer
+  loads on the leaf level, applies eq. 6 (and the optional memcap
+  penalty), max-reduces each level over its sublane range and sums the
+  level maxima deepest level first;
+* the output is lane-dense, ``(1, P)``.
 
-Level segmentation is static per hierarchy, so the per-level max is an
-unrolled ``depth``-step masked reduce over the one-hot ``(depth, D)``
-level table — no scatter, no dynamic slicing. Like the fedavg kernel,
-math accumulates in f32: parity tests pin the kernel against the jnp
-oracle (``kernels.ref.tpd_ref``) exactly and against the float64 scalar
-model within f32 tolerance. ``CostModel.batch_tpd`` dispatches here for
-large batches on TPU and GPU backends — the tile size follows the
-backend (:func:`default_block_p`): 8-particle tiles match the TPU's
-sublane granularity, while GPU blocks want wider (64-particle) tiles
-so each ``pallas_call`` step keeps enough rows to occupy a thread
-block. ``interpret=True`` executes the kernel body under the Pallas
-interpreter on any host — ``CostModel.batch_tpd(backend="interpret")``
-is the CI escape hatch that exercises it without an accelerator.
+The trainer-split leaf loads (a rank-among-unplaced scatter) are
+computed host-side by ``CostModel._make_pallas_tpd`` with the same
+bincount trick the numpy evaluator uses, and stream in as a slot-major
+``(L, P)`` operand.
+
+Math accumulates in f32 in the same order as the jnp oracle
+``kernels.ref.tpd_ref`` (kid payloads summed in kid order, per-level
+maxima summed deepest first), so the parity tests pin the two exactly,
+and both against the float64 scalar model within f32 tolerance.
+``CostModel.batch_tpd`` compiles this kernel for large batches on TPU;
+``interpret=True`` runs the same body under the Pallas interpreter on
+any host, which is how the CPU test suite exercises it.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_P = 8        # TPU sublane-sized particle tile
-DEFAULT_BLOCK_P_GPU = 64   # wider tiles to fill a GPU thread block
-_NEG = -3.4e38  # f32-safe -inf stand-in for the masked level max
+LANES = 128  # particles per grid step: one lane-dense tile
+_NEG = -3.4e38  # f32-safe -inf stand-in: floor of every level max
 
 
-def default_block_p(backend: Optional[str] = None) -> int:
-    """Particle-tile size for ``backend`` (``"tpu"``/``"gpu"``/None).
+def _tpd_kernel(depth, width, penalty, host_ref, leaf_ref, o_ref):
+    # host_ref (A, D, LANES): rows of [mdatasize, pspeed(, memcap)] per
+    # slot host; leaf_ref (L, LANES) trainer loads; o_ref (1, LANES)
+    starts = _level_starts(depth, width)
+    total = jnp.zeros((1, LANES), jnp.float32)
+    for lv in range(depth - 1, -1, -1):                  # deepest first
+        a, n = starts[lv], width ** lv
+        if lv == depth - 1:
+            child = leaf_ref[...]
+        else:   # kid w of every slot: stride-width rows of level lv + 1
+            b = starts[lv + 1]
+            child = host_ref[0, pl.ds(b, n, stride=width), :]
+            for w in range(1, width):
+                child = child + host_ref[0, pl.ds(b + w, n, stride=width), :]
+        load = host_ref[0, a:a + n, :] + child
+        delay = load / host_ref[1, a:a + n, :]
+        if penalty > 0:
+            cap = host_ref[2, a:a + n, :]
+            over = jnp.maximum(0.0, load - cap)
+            delay = delay * (1.0 + penalty * over / jnp.maximum(cap, 1e-9))
+        # the floor is an identity on any delay, but keeps a one-slot
+        # level's max an explicit op: XLA's CPU backend would otherwise
+        # fuse the root's eq. 6 product into the running sum as one
+        # multiply-add and the interpreter would drift from the oracle
+        total = total + jnp.maximum(jnp.max(delay, axis=0, keepdims=True),
+                                    _NEG)
+    o_ref[...] = total
 
-    None (or any non-GPU backend, interpret mode included) keeps the
-    TPU-shaped default — the interpreter's numerics don't depend on the
-    tile, so small tiles keep CI cheap.
-    """
-    return DEFAULT_BLOCK_P_GPU if backend == "gpu" else DEFAULT_BLOCK_P
 
-
-def tpd_kernel_inputs(hierarchy):
-    """Static operand tables for one hierarchy: (kids, kids_valid,
-    is_leaf, slot_leaf_idx, level_onehot) as jnp arrays."""
-    h = hierarchy
-    D, depth = h.dimensions, h.depth
-    leaf_start = h.level_starts[depth - 1]
-    kids = h.kids_table
-    level_onehot = np.zeros((depth, D), np.float32)
-    level_onehot[h.levels, np.arange(D)] = 1.0
-    return (jnp.asarray(np.clip(kids, 0, D - 1)),
-            jnp.asarray((kids >= 0).astype(np.float32)),
-            jnp.asarray((h.levels == depth - 1).astype(np.float32)),
-            jnp.asarray(np.clip(np.arange(D) - leaf_start, 0,
-                                h.n_leaves - 1).astype(np.int32)),
-            jnp.asarray(level_onehot))
-
-
-def _tpd_kernel(penalty, depth,
-                p_ref, attrs_ref, leaf_ref, kids_ref, kidsv_ref,
-                is_leaf_ref, leaf_idx_ref, level_ref, o_ref):
-    p = p_ref[...]                                   # (BP, D) int32
-    attrs = attrs_ref[...].astype(jnp.float32)       # (3, C)
-    leaf_load = leaf_ref[...].astype(jnp.float32)    # (BP, L)
-    kids = kids_ref[...]                             # (D, W) int32
-    kidsv = kidsv_ref[...]                           # (D, W) f32 mask
-    is_leaf = is_leaf_ref[...]                       # (D,) f32 mask
-    leaf_idx = leaf_idx_ref[...]                     # (D,) int32
-    level = level_ref[...]                           # (depth, D) one-hot
-
-    mds, pspeed, memcap = attrs[0], attrs[1], attrs[2]
-    host_mds = jnp.take(mds, p)                      # fused gathers
-    kid_host = jnp.take(p, kids, axis=1)             # (BP, D, W)
-    kid_mds = jnp.take(mds, kid_host) * kidsv[None]
-    child = jnp.sum(kid_mds, axis=2)
-    leaf_child = jnp.take(leaf_load, leaf_idx, axis=1)
-    load = host_mds + is_leaf[None] * leaf_child \
-        + (1.0 - is_leaf[None]) * child
-    delay = load / jnp.take(pspeed, p)
-    if penalty > 0:
-        cap = jnp.take(memcap, p)
-        over = jnp.maximum(0.0, load - cap)
-        delay = delay * (1.0 + penalty * over / jnp.maximum(cap, 1e-9))
-
-    total = jnp.zeros(delay.shape[:1], jnp.float32)
-    for lv in range(depth - 1, -1, -1):              # deepest level first
-        masked = jnp.where(level[lv][None] > 0, delay, _NEG)
-        total = total + jnp.max(masked, axis=1)
-    o_ref[...] = total.astype(o_ref.dtype)
+def _level_starts(depth: int, width: int) -> list:
+    """First slot of each level (and one past the last), heap order —
+    ``Hierarchy.level_starts`` for the same shape."""
+    starts = [0]
+    for lv in range(depth):
+        starts.append(starts[-1] + width ** lv)
+    return starts
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("penalty", "block_p", "interpret"))
-def batch_tpd_pallas(placements, attrs, leaf_load, kids, kids_valid,
-                     is_leaf, slot_leaf_idx, level_onehot, *,
-                     penalty: float = 0.0,
-                     block_p: int = DEFAULT_BLOCK_P,
+                   static_argnames=("depth", "width", "penalty", "interpret"))
+def batch_tpd_pallas(placements, attrs, leaf_load, *, depth: int,
+                     width: int, penalty: float = 0.0,
                      interpret: bool = False) -> jnp.ndarray:
-    """placements (P, D) int32, attrs (3, C) f32, leaf_load (P, L) f32
-    -> (P,) f32 TPDs. Static tables from :func:`tpd_kernel_inputs`.
+    """placements (P, D) int32, attrs (3, C) f32 = [mdatasize, pspeed,
+    memcap], leaf_load (P, L) f32 -> (P,) f32 TPDs for the complete
+    ``width``-ary tree of ``depth`` levels.
 
-    Grid walks particle tiles; each step re-reads the (small) static
-    tables from VMEM and fuses the whole eq. 6/7 evaluation for its
-    ``block_p`` particles.
+    The grid walks 128-particle lane tiles; rows past ``P`` are padded
+    with copies of row 0 and sliced off.
     """
     P, D = placements.shape
-    depth, _ = level_onehot.shape
     L = leaf_load.shape[1]
-    block_p = min(block_p, P)
-    pad = (-P) % block_p
+    if D != _level_starts(depth, width)[-1] or L != width ** (depth - 1):
+        raise ValueError(f"placements {placements.shape} / leaf_load "
+                         f"{leaf_load.shape} do not fit a depth-{depth} "
+                         f"width-{width} tree")
+    pad = (-P) % LANES
     if pad:  # pad with copies of row 0 (any valid row; sliced off below)
         placements = jnp.concatenate(
             [placements, jnp.broadcast_to(placements[:1], (pad, D))])
         leaf_load = jnp.concatenate(
             [leaf_load, jnp.broadcast_to(leaf_load[:1], (pad, L))])
-    grid = ((P + pad) // block_p,)
+    rows = 3 if penalty > 0 else 2      # memcap only feeds the penalty
+    host = jnp.stack([attrs[r].astype(jnp.float32)[placements.T]
+                      for r in range(rows)])                  # (A, D, Pp)
+    leaf = leaf_load.astype(jnp.float32).T                    # (L, Pp)
     out = pl.pallas_call(
-        functools.partial(_tpd_kernel, float(penalty), depth),
-        out_shape=jax.ShapeDtypeStruct((P + pad,), jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_p, D), lambda i: (i, 0)),
-            pl.BlockSpec(attrs.shape, lambda i: (0, 0)),
-            pl.BlockSpec((block_p, L), lambda i: (i, 0)),
-            pl.BlockSpec(kids.shape, lambda i: (0, 0)),
-            pl.BlockSpec(kids_valid.shape, lambda i: (0, 0)),
-            pl.BlockSpec(is_leaf.shape, lambda i: (0,)),
-            pl.BlockSpec(slot_leaf_idx.shape, lambda i: (0,)),
-            pl.BlockSpec(level_onehot.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_p,), lambda i: (i,)),
+        functools.partial(_tpd_kernel, depth, width, float(penalty)),
+        out_shape=jax.ShapeDtypeStruct((1, P + pad), jnp.float32),
+        grid=((P + pad) // LANES,),
+        in_specs=[pl.BlockSpec((rows, D, LANES), lambda i: (0, 0, i)),
+                  pl.BlockSpec((L, LANES), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, LANES), lambda i: (0, i)),
         interpret=interpret,
-    )(placements, attrs, leaf_load, kids, kids_valid,
-      is_leaf, slot_leaf_idx, level_onehot)
-    return out[:P]
+    )(host, leaf)
+    return out[0, :P]

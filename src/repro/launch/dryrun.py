@@ -33,6 +33,8 @@ import jax  # noqa: E402
 
 from repro.configs import ASSIGNED, SHAPES  # noqa: E402
 from repro.launch.mesh import (  # noqa: E402
+    TARGET_DEVICE_KIND,
+    make_mesh,
     make_production_mesh,
     mesh_chip_count,
 )
@@ -77,7 +79,7 @@ def dryrun_one(arch: str, shape: str, multi_pod: bool = False,
                seq_shard: bool = True, mesh_shape=None) -> dict:
     if mesh_shape is not None:
         d, m = mesh_shape
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         mesh_name = f"{d}x{m}"
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
@@ -103,6 +105,7 @@ def dryrun_one(arch: str, shape: str, multi_pod: bool = False,
     record = {
         "arch": arch, "shape": shape, "mesh": mesh_name,
         "chips": mesh_chip_count(mesh),
+        "device_kind": TARGET_DEVICE_KIND,
         "kind": bundle.kind, "mode": bundle.mode, "meta": bundle.meta,
         "memory": _memory_dict(compiled),
         "cost": _cost_dict(compiled),          # XLA (loop-bodies-once)

@@ -80,4 +80,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

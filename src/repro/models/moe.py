@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
-from repro.kernels import compat
 from repro.models.common import dense_init
 from repro.models.sharding import ShardingPolicy
 
@@ -187,7 +186,7 @@ def moe_ffn(params: dict, x: jnp.ndarray, cfg: MoEConfig,
     w_in_spec = P(model_axis, fsdp, None)     # (E, D, F): E over model, D fsdp
     w_out_spec = P(model_axis, None, fsdp)    # (E, F, D)
 
-    out2d = compat.shard_map(
+    out2d = jax.shard_map(
         shard_fn,
         mesh=policy.mesh,
         in_specs=(x_spec, gates_spec, w_in_spec, w_in_spec, w_out_spec),

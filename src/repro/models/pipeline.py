@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.kernels import compat
 from repro.models import common
 from repro.models.sharding import ShardingPolicy
 from repro.models.transformer import embed_inputs, logits_fn, make_block_fn
@@ -132,7 +131,7 @@ def make_pp_loss_fn(cfg: ModelConfig, policy: ShardingPolicy, mesh: Mesh,
             if _path_str(path).startswith("layers/")
             else P(*((None,) * leaf.ndim)),
             params)
-        return compat.shard_map(
+        return jax.shard_map(
             pp_body, mesh=mesh,
             in_specs=(param_specs,
                       jax.tree.map(lambda _: P(), batch),

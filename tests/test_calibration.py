@@ -5,7 +5,9 @@ Pins, in order:
 
 * trace record -> save -> load round trips byte-identically, and
   recording itself is byte-NEUTRAL — default and ``recording='on'``
-  runs both reproduce the checked-in pre-PR goldens exactly;
+  runs both reproduce the checked-in simulated golden exactly; emulated
+  runs reproduce a same-build twin byte for byte and the golden in
+  every field but the trained model's loss/accuracy;
 * the least-squares fitter recovers the emulated engine's true
   constants (payload scale 1/EQ6_PAYLOAD_SCALE, per-level link =
   comm_latency, train scale = local_steps) and the fitted model
@@ -232,15 +234,40 @@ def test_fig3_byte_identical_to_golden(eval_config):
     assert got == want
 
 
+def _without_training_numerics(d: dict) -> dict:
+    """The artifact minus the trained model's loss/accuracy: those
+    follow the installed JAX build (its PRNG stream and CPU kernels),
+    every other field (placements, TPDs, timings) is build-independent."""
+    d = json.loads(json.dumps(d))
+    for run in d["runs"]:
+        for key in ("metrics", "final_metrics"):
+            run[key].pop("loss")
+            run[key].pop("accuracy")
+    for agg in d["aggregates"].values():
+        agg.pop("final_loss")
+        agg.pop("final_accuracy")
+    return d
+
+
+@pytest.fixture(scope="module")
+def fig4_twin():
+    """Recording-off artifact of THIS JAX build — the emulated pin."""
+    return json.dumps(_fig4_result(eval_config=EvalConfig()).to_dict(),
+                      indent=1)
+
+
 @pytest.mark.parametrize("eval_config", [
     None,
     EvalConfig(recording="on"),
 ], ids=["default", "recording-on"])
-def test_fig4_byte_identical_to_golden(eval_config):
+def test_fig4_byte_identical_to_golden(eval_config, fig4_twin):
     res = _fig4_result(eval_config=eval_config)
     got = json.dumps(res.to_dict(), indent=1)
-    want = (GOLDEN / "recording_off_fig4_mlp_smoke.json").read_text()
-    assert got == want
+    assert got == fig4_twin
+    want = json.loads((GOLDEN / "recording_off_fig4_mlp_smoke.json")
+                      .read_text())
+    assert _without_training_numerics(res.to_dict()) == \
+        _without_training_numerics(want)
 
 
 def test_legacy_mode_kwarg_warns_and_stays_byte_identical():

@@ -80,9 +80,10 @@ MESH_SCRIPT = textwrap.dedent("""
     from repro.models import get_model
     from repro.models.sharding import ShardingPolicy
     from repro.optim import sgd
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("stablelm-1.6b").reduced().replace(n_layers=1)
     policy = ShardingPolicy(mesh=mesh, batch_axes=None, model_axis="model")
     model = get_model(cfg, policy)
@@ -126,7 +127,7 @@ MESH_SCRIPT = textwrap.dedent("""
 def test_hierarchical_psum_on_8_device_mesh():
     """End-to-end numeric check of the grouped-psum aggregation on a real
     (forged) 4x2 device mesh, vs host flat FedAvg."""
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", MESH_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)

@@ -5,12 +5,13 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import SHAPES
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import _resolve_window, build_bundle, fl_replica_feasible, param_bytes
 
 
 @pytest.fixture(scope="module")
 def tiny_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_window_resolution():

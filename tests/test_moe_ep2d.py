@@ -20,7 +20,9 @@ SCRIPT = textwrap.dedent("""
     from repro.models.moe import init_moe, moe_ffn
     from repro.models.sharding import ShardingPolicy, UNSHARDED
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = MoEConfig(n_experts=8, top_k=2, d_ff_expert=64)
     d = 32
     params = init_moe(jax.random.key(0), d, cfg, jnp.float32)
@@ -40,7 +42,7 @@ SCRIPT = textwrap.dedent("""
 
 
 def test_ep2d_matches_unsharded():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)

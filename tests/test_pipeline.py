@@ -1,22 +1,13 @@
 """Pipeline parallelism: GPipe over the pod axis must be numerically
-identical (loss AND grads) to the unpipelined model. Forged 2-pod mesh
-in a subprocess.
-
-Mesh shape is picked by the compat shim's capability probe: native
-``jax.shard_map`` (check_vma signature) lowers the partial-auto
-(2, 2, 2) mesh; legacy 0.4.x cannot (XLA hard-CHECKs on partial-auto
-CPU meshes), so there the pod axis still gets 2 stages but data/model
-collapse to trivial size-1 axes and grads flow through the compat
-shim's repaired legacy transpose rule."""
+identical (loss AND grads) to the unpipelined model. Forged (2, 2, 2)
+pod/data/model mesh in a subprocess: partial-auto ``jax.shard_map``,
+manual over the pod axis only."""
 import json
 import os
 import subprocess
 import sys
 import textwrap
 
-import pytest
-
-from repro.kernels import compat
 
 SCRIPT = textwrap.dedent("""
     import os
@@ -29,12 +20,9 @@ SCRIPT = textwrap.dedent("""
     from repro.models import get_model
     from repro.models.pipeline import make_pp_loss_fn
     from repro.models.sharding import ShardingPolicy
-    from repro.kernels import compat
+    from repro.launch.mesh import make_mesh
 
-    # partial-auto meshes only lower on native shard_map; legacy JAX
-    # keeps the 2 pipeline stages with trivial data/model axes
-    shape = (2, 2, 2) if compat.shard_map_is_native() else (2, 1, 1)
-    mesh = jax.make_mesh(shape, ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_config("stablelm-1.6b").reduced().replace(
         n_layers=2, remat=False, dtype="float32")  # f32: exact comparison
     policy = ShardingPolicy(mesh=mesh)  # unsharded inside stages (tiny)
@@ -66,11 +54,8 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
-@pytest.mark.skipif(
-    not compat.has_shard_map(),
-    reason="no shard_map implementation resolves (native or legacy)")
 def test_pipeline_matches_unpipelined():
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)

@@ -203,10 +203,24 @@ def test_pooled_evaluator_rejects_mismatched_models():
 # ---------------------------------------------------------------------------
 # Pallas kernel vs its jnp oracle
 # ---------------------------------------------------------------------------
+def _leaf_loads(ps, attrs, C, L):
+    """(P, L) trainer loads per leaf: the host prologue of the Pallas
+    path (rank among unplaced ids, mod leaves)."""
+    P = ps.shape[0]
+    p_off = np.arange(P)[:, None]
+    unplaced = np.bincount((ps + C * p_off).ravel(),
+                           minlength=P * C).reshape(P, C) == 0
+    t_mds = np.where(unplaced, attrs[0][None], np.float32(0.0))
+    leaf_of = (np.cumsum(unplaced, axis=1) - 1) % L
+    return np.bincount((leaf_of + L * p_off).ravel(),
+                       weights=t_mds.ravel(),
+                       minlength=P * L).reshape(P, L).astype(np.float32)
+
+
 def test_pallas_tpd_kernel_matches_oracle_exactly():
     import jax.numpy as jnp
     from repro.kernels.ref import tpd_ref
-    from repro.kernels.tpd import batch_tpd_pallas, tpd_kernel_inputs
+    from repro.kernels.tpd import batch_tpd_pallas
 
     h = Hierarchy(depth=4, width=3, trainers_per_leaf=2, n_clients=200)
     rng = np.random.default_rng(0)
@@ -215,22 +229,48 @@ def test_pallas_tpd_kernel_matches_oracle_exactly():
     cm = CostModel(h, pool, memory_penalty=2.5)
     P, C, L = 7, 200, h.n_leaves
     ps = _placements(h, P, seed=2)
-    tables = tpd_kernel_inputs(h)
     attrs = cm._attr_stack(np.float32)
-    p_off = np.arange(P)[:, None]
-    unplaced = np.bincount((ps + C * p_off).ravel(),
-                           minlength=P * C).reshape(P, C) == 0
-    t_mds = np.where(unplaced, attrs[0][None], np.float32(0.0))
-    leaf_of = (np.cumsum(unplaced, axis=1) - 1) % L
-    leaf_load = np.bincount((leaf_of + L * p_off).ravel(),
-                            weights=t_mds.ravel(),
-                            minlength=P * L).reshape(P, L).astype(np.float32)
+    leaf_load = _leaf_loads(ps, attrs, C, L)
+    shape = dict(depth=h.depth, width=h.width, penalty=2.5)
     kern = batch_tpd_pallas(jnp.asarray(ps), jnp.asarray(attrs),
-                            jnp.asarray(leaf_load), *tables,
-                            penalty=2.5, interpret=True)
+                            jnp.asarray(leaf_load), interpret=True, **shape)
     ref = tpd_ref(jnp.asarray(ps), jnp.asarray(attrs),
-                  jnp.asarray(leaf_load), *tables, penalty=2.5)
+                  jnp.asarray(leaf_load), **shape)
     assert jnp.array_equal(kern, ref)  # atol=0 vs the jnp oracle
+    scalar = np.array([cm.tpd(p) for p in ps])
+    np.testing.assert_allclose(np.asarray(kern), scalar, rtol=2e-5)
+
+
+@pytest.mark.parametrize("depth,width,n_clients,P,penalty", [
+    (4, 3, 200, 300, 1.5),   # three 128-particle lane tiles + pad
+    (3, 4, 53, 20, 0.0),     # the paper-fig3 tree
+    (3, 1, 12, 5, 2.0),      # width 1: a chain, stride-1 kid rows
+    (1, 3, 6, 9, 1.0),       # depth 1: the root is the only level
+])
+def test_pallas_tpd_lane_tiles_match_tpd_ref(depth, width, n_clients, P,
+                                             penalty):
+    """The kernel over tree shapes and particle counts that cross the
+    128-particle lane tile: pinned exactly against the jnp oracle and
+    within f32 tolerance against the float64 scalar model."""
+    import jax.numpy as jnp
+    from repro.kernels.ref import tpd_ref
+    from repro.kernels.tpd import batch_tpd_pallas
+
+    h = Hierarchy(depth=depth, width=width, trainers_per_leaf=2,
+                  n_clients=n_clients)
+    rng = np.random.default_rng(3)
+    pool = ClientPool.random(n_clients, seed=3)
+    pool.mdatasize = rng.uniform(1.0, 40.0, n_clients)
+    cm = CostModel(h, pool, memory_penalty=penalty)
+    ps = _placements(h, P, seed=4)
+    attrs = cm._attr_stack(np.float32)
+    leaf_load = _leaf_loads(ps, attrs, n_clients, h.n_leaves)
+    shape = dict(depth=depth, width=width, penalty=penalty)
+    ref = tpd_ref(jnp.asarray(ps), jnp.asarray(attrs),
+                  jnp.asarray(leaf_load), **shape)
+    kern = batch_tpd_pallas(jnp.asarray(ps), jnp.asarray(attrs),
+                            jnp.asarray(leaf_load), interpret=True, **shape)
+    assert jnp.array_equal(kern, ref)
     scalar = np.array([cm.tpd(p) for p in ps])
     np.testing.assert_allclose(np.asarray(kern), scalar, rtol=2e-5)
 
@@ -351,7 +391,7 @@ def test_scale_presets_registered_and_runnable():
 
 
 # ---------------------------------------------------------------------------
-# interpret escape hatch + GPU tiling (kernel body exercised off-TPU)
+# interpret escape hatch (kernel body exercised off-TPU)
 # ---------------------------------------------------------------------------
 def test_batch_tpd_interpret_escape_hatch():
     """backend='interpret' forces the Pallas INTERPRETER on any host:
@@ -367,51 +407,6 @@ def test_batch_tpd_interpret_escape_hatch():
         got, np.asarray(cm.batch_tpd(ps, backend="pallas")))
     with pytest.raises(ValueError, match="backend"):
         cm.batch_tpd(ps, backend="bogus")
-
-
-def test_pallas_gpu_tile_matches_tpd_ref():
-    """The GPU tile width (DEFAULT_BLOCK_P_GPU) through the
-    interpreter: numerics must not depend on the particle-tile size,
-    pinned exactly against the jnp oracle tpd_ref."""
-    import jax.numpy as jnp
-    from repro.kernels.ref import tpd_ref
-    from repro.kernels.tpd import (
-        DEFAULT_BLOCK_P,
-        DEFAULT_BLOCK_P_GPU,
-        batch_tpd_pallas,
-        default_block_p,
-        tpd_kernel_inputs,
-    )
-
-    assert default_block_p("gpu") == DEFAULT_BLOCK_P_GPU
-    assert default_block_p("tpu") == DEFAULT_BLOCK_P
-    assert default_block_p(None) == DEFAULT_BLOCK_P
-
-    h = Hierarchy(depth=4, width=3, trainers_per_leaf=2, n_clients=200)
-    rng = np.random.default_rng(3)
-    pool = ClientPool.random(200, seed=3)
-    pool.mdatasize = rng.uniform(1.0, 40.0, 200)
-    cm = CostModel(h, pool, memory_penalty=1.5)
-    P, C, L = 70, 200, h.n_leaves  # > one GPU tile, non-divisible pad
-    ps = _placements(h, P, seed=4)
-    tables = tpd_kernel_inputs(h)
-    attrs = cm._attr_stack(np.float32)
-    p_off = np.arange(P)[:, None]
-    unplaced = np.bincount((ps + C * p_off).ravel(),
-                           minlength=P * C).reshape(P, C) == 0
-    t_mds = np.where(unplaced, attrs[0][None], np.float32(0.0))
-    leaf_of = (np.cumsum(unplaced, axis=1) - 1) % L
-    leaf_load = np.bincount((leaf_of + L * p_off).ravel(),
-                            weights=t_mds.ravel(),
-                            minlength=P * L).reshape(P, L).astype(np.float32)
-    ref = tpd_ref(jnp.asarray(ps), jnp.asarray(attrs),
-                  jnp.asarray(leaf_load), *tables, penalty=1.5)
-    for block_p in (DEFAULT_BLOCK_P, DEFAULT_BLOCK_P_GPU):
-        kern = batch_tpd_pallas(jnp.asarray(ps), jnp.asarray(attrs),
-                                jnp.asarray(leaf_load), *tables,
-                                penalty=1.5, block_p=block_p,
-                                interpret=True)
-        assert jnp.array_equal(kern, ref), f"block_p={block_p}"
 
 
 # ---------------------------------------------------------------------------
@@ -471,7 +466,7 @@ def test_pooled_tpds_sharded_multi_device_vs_sequential_oracle():
             "scale": float(np.abs(oracle).max()),
         }))
     """)
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                      "src")
     out = subprocess.run([sys.executable, "-c", script], env=env,
