@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hierarchy import ClientPool, Hierarchy, rows_with_duplicates
+from repro.utils import tracing
 
 
 @dataclass(frozen=True)
@@ -512,23 +513,27 @@ class CostModel:
         penalty = float(self.memory_penalty)
 
         def run(placements):
-            placements = np.asarray(placements, np.int32)
-            P = placements.shape[0]
-            p_off = np.arange(P)[:, None]
-            placed = np.bincount((placements + C * p_off).ravel(),
-                                 minlength=P * C).reshape(P, C)
-            unplaced = placed == 0
-            t_mds = np.where(unplaced, attrs[0][None], np.float32(0.0))
-            leaf_of = (np.cumsum(unplaced, axis=1) - 1) % n_leaves
-            leaf_load = np.bincount(
-                (leaf_of + n_leaves * p_off).ravel(), weights=t_mds.ravel(),
-                minlength=P * n_leaves).reshape(P, n_leaves)
-            out = batch_tpd_pallas(
-                jnp.asarray(placements), jnp.asarray(attrs),
-                jnp.asarray(leaf_load.astype(np.float32)),
-                depth=h.depth, width=h.width, penalty=penalty,
-                interpret=interpret)
-            return np.asarray(out)
+            with tracing.span("tpd.prologue"):
+                placements = np.asarray(placements, np.int32)
+                P = placements.shape[0]
+                p_off = np.arange(P)[:, None]
+                placed = np.bincount((placements + C * p_off).ravel(),
+                                     minlength=P * C).reshape(P, C)
+                unplaced = placed == 0
+                t_mds = np.where(unplaced, attrs[0][None], np.float32(0.0))
+                leaf_of = (np.cumsum(unplaced, axis=1) - 1) % n_leaves
+                leaf_load = np.bincount(
+                    (leaf_of + n_leaves * p_off).ravel(),
+                    weights=t_mds.ravel(),
+                    minlength=P * n_leaves).reshape(P, n_leaves)
+            with tracing.span("tpd.transfer"):
+                out = batch_tpd_pallas(
+                    jnp.asarray(placements), jnp.asarray(attrs),
+                    jnp.asarray(leaf_load.astype(np.float32)),
+                    depth=h.depth, width=h.width, penalty=penalty,
+                    interpret=interpret)
+            with tracing.span("tpd.wait"):
+                return np.asarray(out)
 
         return run
 

@@ -41,6 +41,7 @@ from typing import Callable, List, Optional, Union
 import numpy as np
 
 from repro.core.hierarchy import fill_placement_holes, rows_with_duplicates
+from repro.utils import tracing
 
 
 @dataclass
@@ -319,17 +320,22 @@ class FlagSwapPSO:
         Returns the gbest placement. Bit-identical trajectories to
         ``_run_reference`` (parity-pinned)."""
         for _ in range(iterations):
-            # a copy: fitness callables must not corrupt the dedup cache
-            placements = self.placements()
-            if batch_fitness_fn is not None:
-                fs = np.asarray(batch_fitness_fn(placements), np.float64)
-            else:
-                fs = np.array([fitness_fn(p) for p in placements],
-                              np.float64)
-            self.evaluations += self.n_particles
-            self.history.record(-fs)  # record TPD (positive)
-            self._update_bests_swarm(fs)
-            self._step_swarm()
+            # the id counts every iteration this swarm has recorded
+            with tracing.span("search.iteration",
+                              iteration=len(self.history.best)):
+                # a copy: fitness callables must not corrupt the dedup cache
+                placements = self.placements()
+                with tracing.span("search.score"):
+                    if batch_fitness_fn is not None:
+                        fs = np.asarray(batch_fitness_fn(placements),
+                                        np.float64)
+                    else:
+                        fs = np.array([fitness_fn(p) for p in placements],
+                                      np.float64)
+                self.evaluations += self.n_particles
+                self.history.record(-fs)  # record TPD (positive)
+                self._update_bests_swarm(fs)
+                self._step_swarm()
         return self._dedup(self.gbest_x)
 
     def _run_reference(self, fitness_fn: Callable, iterations: int = 100,

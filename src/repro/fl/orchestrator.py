@@ -46,6 +46,7 @@ from repro.faults.tolerance import quorum_count, quorum_merge_batched
 from repro.fl.aggregation import SegmentAggregator
 from repro.fl.distributed import elastic_rehierarchize
 from repro.models.api import Model
+from repro.utils import tracing
 from repro.utils.trees import tree_weighted_sum
 
 # rng stream tag for elastic data provisioning: joiner shards draw from
@@ -288,27 +289,28 @@ class FederatedOrchestrator:
         cohort (the online track trains partial cohorts); ``None``
         means every client, in id order.
         """
-        if ids is None:
-            ids = range(self.hierarchy.total_clients)
-        buckets: Dict[tuple, list] = {}
-        for c in ids:
-            c = int(c)
-            steps = [self.data.client_batch(
-                c, self.batch_size, round_idx * self.local_steps + s)
-                for s in range(self.local_steps)]
-            sig = tuple(sorted((k, v.shape, str(np.asarray(v).dtype))
-                               for k, v in steps[0].items()))
-            buckets.setdefault(sig, []).append((c, steps))
-        out = []
-        for _sig, entries in buckets.items():
-            ids = np.asarray([c for c, _ in entries], np.int64)
-            keys = entries[0][1][0].keys()
-            stacked = {k: np.stack([np.stack([np.asarray(st[k])
-                                              for st in steps])
-                                    for _, steps in entries])
-                       for k in keys}
-            out.append((ids, stacked))
-        return out
+        with tracing.span("round.inputs"):
+            if ids is None:
+                ids = range(self.hierarchy.total_clients)
+            buckets: Dict[tuple, list] = {}
+            for c in ids:
+                c = int(c)
+                steps = [self.data.client_batch(
+                    c, self.batch_size, round_idx * self.local_steps + s)
+                    for s in range(self.local_steps)]
+                sig = tuple(sorted((k, v.shape, str(np.asarray(v).dtype))
+                                   for k, v in steps[0].items()))
+                buckets.setdefault(sig, []).append((c, steps))
+            out = []
+            for _sig, entries in buckets.items():
+                ids = np.asarray([c for c, _ in entries], np.int64)
+                keys = entries[0][1][0].keys()
+                stacked = {k: np.stack([np.stack([np.asarray(st[k])
+                                                  for st in steps])
+                                        for _, steps in entries])
+                           for k in keys}
+                out.append((ids, stacked))
+            return out
 
     def _local_fn_for(self, sig: tuple) -> Callable:
         fn = self._local_fns.get(sig)
@@ -334,19 +336,27 @@ class FederatedOrchestrator:
         self._local_fns[sig] = fn
         return fn
 
+    def _dispatch_local(self, round_idx: int, ids=None):
+        """One ``local_all`` call per batch-shape bucket of the cohort
+        (``ids``; ``None`` = every client). Returns ([(bucket client ids,
+        updated params)], wall seconds until the last call is done)."""
+        t0 = time.perf_counter()
+        pieces: List[Tuple[np.ndarray, object]] = []
+        for bucket_ids, stacked in self._collect_batches(round_idx, ids):
+            sig = tuple(sorted((k, v.shape[2:], str(v.dtype))
+                               for k, v in stacked.items()))
+            new_p, _ = self._local_fn_for(sig)(self.params, stacked)
+            tracing.count("round.local_calls")
+            pieces.append((bucket_ids, new_p))
+        with tracing.span("round.wait"):
+            jax.block_until_ready(jax.tree.leaves(pieces[-1][1])[0])
+        return pieces, time.perf_counter() - t0
+
     def _train_all_batched(self, round_idx: int):
         """All clients' local training. Returns (stacked_updates (C,...),
         train_times (C,))."""
         C = self.hierarchy.total_clients
-        t0 = time.perf_counter()
-        pieces: List[Tuple[np.ndarray, object]] = []
-        for ids, stacked in self._collect_batches(round_idx):
-            sig = tuple(sorted((k, v.shape[2:], str(v.dtype))
-                               for k, v in stacked.items()))
-            new_p, _ = self._local_fn_for(sig)(self.params, stacked)
-            pieces.append((ids, new_p))
-        jax.block_until_ready(jax.tree.leaves(pieces[-1][1])[0])
-        wall = time.perf_counter() - t0
+        pieces, wall = self._dispatch_local(round_idx)
 
         if len(pieces) == 1 and np.array_equal(
                 pieces[0][0], np.arange(C)):
@@ -375,57 +385,58 @@ class FederatedOrchestrator:
         measured timing splits each level's wall clock across its
         clusters by payload share before the pspeed/comm composition.
         """
-        h = self.hierarchy
-        plan = h.round_plan(placement)
-        mds = self.clients.mdatasize
-        depth = h.depth
+        with tracing.span("round.merge"):
+            h = self.hierarchy
+            plan = h.round_plan(placement)
+            mds = self.clients.mdatasize
+            depth = h.depth
 
-        def level_time(lp, cluster_dt, idx, raw_loads) -> float:
-            """pspeed/comm/noise composition, vectorized per level (one
-            rng draw per cluster, same stream order as the loop engine)."""
-            ts = (cluster_dt / self.clients.pspeed[lp.hosts]
-                  + self.comm_latency * lp.n_parts)
-            if self.rng_noise:
-                ts = ts * (1.0 + self.rng.normal(0, self.rng_noise,
-                                                 size=lp.n_clusters))
-            if self._trace is not None:
-                level = depth - 1 - idx  # plan levels are deepest first
-                start = h.level_starts[level]
-                self._trace["levels"].append({
-                    "level": level,
-                    "slots": list(range(start, start + lp.n_clusters)),
-                    "hosts": lp.hosts.tolist(),
-                    "loads": np.asarray(raw_loads, np.float64).tolist(),
-                    "n_parts": lp.n_parts.tolist(),
-                    "delays": np.asarray(ts, np.float64).tolist()})
-            return float(ts.max())
+            def level_time(lp, cluster_dt, idx, raw_loads) -> float:
+                """pspeed/comm/noise composition, vectorized per level (one
+                rng draw per cluster, same stream order as the loop engine)."""
+                ts = (cluster_dt / self.clients.pspeed[lp.hosts]
+                      + self.comm_latency * lp.n_parts)
+                if self.rng_noise:
+                    ts = ts * (1.0 + self.rng.normal(0, self.rng_noise,
+                                                     size=lp.n_clusters))
+                if self._trace is not None:
+                    level = depth - 1 - idx  # plan levels are deepest first
+                    start = h.level_starts[level]
+                    self._trace["levels"].append({
+                        "level": level,
+                        "slots": list(range(start, start + lp.n_clusters)),
+                        "hosts": lp.hosts.tolist(),
+                        "loads": np.asarray(raw_loads, np.float64).tolist(),
+                        "n_parts": lp.n_parts.tolist(),
+                        "delays": np.asarray(ts, np.float64).tolist()})
+                return float(ts.max())
 
-        if self.timing == "deterministic":
-            # charge eq. 6 analytically; run the whole aggregation as
-            # ONE jit call (no per-level host syncs needed)
-            new_global = self._agg.aggregate_fused(
-                stacked_updates, self.weights, plan)
+            if self.timing == "deterministic":
+                # charge eq. 6 analytically; run the whole aggregation as
+                # ONE jit call (no per-level host syncs needed)
+                new_global = self._agg.aggregate_fused(
+                    stacked_updates, self.weights, plan)
+                total = 0.0
+                for idx, lp in enumerate(plan.levels):
+                    loads = np.zeros(lp.n_clusters)
+                    np.add.at(loads, lp.seg, mds[lp.member_clients])
+                    total += level_time(lp, loads / self.EQ6_PAYLOAD_SCALE,
+                                        idx, loads)
+                return new_global, total
+
+            weighted = self._agg.weighted(stacked_updates, self.weights)
             total = 0.0
+            vals = None
             for idx, lp in enumerate(plan.levels):
+                t0 = time.perf_counter()
+                vals = self._agg.run_level(idx, weighted, vals, plan)
+                jax.block_until_ready(jax.tree.leaves(vals)[0])
+                wall = time.perf_counter() - t0
                 loads = np.zeros(lp.n_clusters)
                 np.add.at(loads, lp.seg, mds[lp.member_clients])
-                total += level_time(lp, loads / self.EQ6_PAYLOAD_SCALE,
+                total += level_time(lp, wall * loads / max(loads.sum(), 1e-12),
                                     idx, loads)
-            return new_global, total
-
-        weighted = self._agg.weighted(stacked_updates, self.weights)
-        total = 0.0
-        vals = None
-        for idx, lp in enumerate(plan.levels):
-            t0 = time.perf_counter()
-            vals = self._agg.run_level(idx, weighted, vals, plan)
-            jax.block_until_ready(jax.tree.leaves(vals)[0])
-            wall = time.perf_counter() - t0
-            loads = np.zeros(lp.n_clusters)
-            np.add.at(loads, lp.seg, mds[lp.member_clients])
-            total += level_time(lp, wall * loads / max(loads.sum(), 1e-12),
-                                idx, loads)
-        return jax.tree.map(lambda x: x[0], vals), total
+            return jax.tree.map(lambda x: x[0], vals), total
 
     def _round_batched(self, r: int, placement: np.ndarray):
         if self._agg is None:
@@ -461,15 +472,7 @@ class FederatedOrchestrator:
             return self._train_all_batched(round_idx)
         if ids.size == 0:
             return None, np.zeros(0, np.float64)
-        t0 = time.perf_counter()
-        pieces: List[Tuple[np.ndarray, object]] = []
-        for bucket_ids, stacked in self._collect_batches(round_idx, ids):
-            sig = tuple(sorted((k, v.shape[2:], str(v.dtype))
-                               for k, v in stacked.items()))
-            new_p, _ = self._local_fn_for(sig)(self.params, stacked)
-            pieces.append((bucket_ids, new_p))
-        jax.block_until_ready(jax.tree.leaves(pieces[-1][1])[0])
-        wall = time.perf_counter() - t0
+        pieces, wall = self._dispatch_local(round_idx, ids)
 
         order = np.concatenate([b for b, _ in pieces])
         if len(pieces) == 1 and np.array_equal(order, ids):
@@ -523,14 +526,16 @@ class FederatedOrchestrator:
 
     # ==================================================================
     def _evaluate(self, n: int = 512) -> tuple:
-        if hasattr(self.data, "eval_batch"):
-            batch = self.data.eval_batch(n)
-        else:
-            base = self.data.base
-            idx = np.arange(min(n, len(base)))
-            batch = {"x": base.features[idx], "y": base.labels[idx]}
-        loss, metrics = self._eval(self.params, batch)
-        return float(loss), float(metrics.get("acc", 0.0))
+        with tracing.span("round.eval"):
+            if hasattr(self.data, "eval_batch"):
+                batch = self.data.eval_batch(n)
+            else:
+                base = self.data.base
+                idx = np.arange(min(n, len(base)))
+                batch = {"x": base.features[idx], "y": base.labels[idx]}
+            loss, metrics = self._eval(self.params, batch)
+            with tracing.span("round.wait"):
+                return float(loss), float(metrics.get("acc", 0.0))
 
     # ------------------------------------------------------------------
     def warmup(self) -> None:
@@ -670,37 +675,38 @@ class FederatedOrchestrator:
         either path sees bit-identical TPDs. Call ``warmup()`` once
         before the first round.
         """
-        placement = np.asarray(placement, np.int64)
-        self._check_population()
-        self.hierarchy.validate_placement(placement)
+        with tracing.span("round", round=r):
+            placement = np.asarray(placement, np.int64)
+            self._check_population()
+            self.hierarchy.validate_placement(placement)
 
-        self.last_timings = None
-        if self.record_timings:
-            self._trace = {"train": {"clients": [], "times": []},
-                           "levels": []}
-        try:
-            if self.engine == "loop":
-                new_params, train_time, agg_time = \
-                    self._round_loop(r, placement)
-            else:
-                new_params, train_time, agg_time = \
-                    self._round_batched(r, placement)
-        finally:
-            if self._trace is not None:
-                self._trace["train_time"] = 0.0
-                self._trace["agg_time"] = 0.0
-                self.last_timings, self._trace = self._trace, None
-        self.params = new_params
-        if self.last_timings is not None:
-            self.last_timings["train_time"] = float(train_time)
-            self.last_timings["agg_time"] = float(agg_time)
+            self.last_timings = None
+            if self.record_timings:
+                self._trace = {"train": {"clients": [], "times": []},
+                               "levels": []}
+            try:
+                if self.engine == "loop":
+                    new_params, train_time, agg_time = \
+                        self._round_loop(r, placement)
+                else:
+                    new_params, train_time, agg_time = \
+                        self._round_batched(r, placement)
+            finally:
+                if self._trace is not None:
+                    self._trace["train_time"] = 0.0
+                    self._trace["agg_time"] = 0.0
+                    self.last_timings, self._trace = self._trace, None
+            self.params = new_params
+            if self.last_timings is not None:
+                self.last_timings["train_time"] = float(train_time)
+                self.last_timings["agg_time"] = float(agg_time)
 
-        tpd = (train_time + agg_time) * self.time_scale
-        loss, acc = self._evaluate()
-        return RoundRecord(
-            round_idx=r, placement=placement.tolist(), tpd=tpd,
-            train_time=train_time, agg_time=agg_time,
-            loss=loss, accuracy=acc)
+            tpd = (train_time + agg_time) * self.time_scale
+            loss, acc = self._evaluate()
+            return RoundRecord(
+                round_idx=r, placement=placement.tolist(), tpd=tpd,
+                train_time=train_time, agg_time=agg_time,
+                loss=loss, accuracy=acc)
 
     def run_round_faulty(self, r: int, placement, *, down=(), dropped=(),
                          degraded=None, quorum_frac: float = 0.0
