@@ -177,11 +177,18 @@ class FederatedDataset:
         return client_id if self.stream_of is None \
             else self.stream_of[client_id]
 
-    def client_batch(self, client_id: int, batch_size: int, step: int) -> dict:
+    def client_indices(self, client_id: int, batch_size: int,
+                       step: int) -> np.ndarray:
+        """Positions into ``base`` of the client's batch at ``step``:
+        ``min(batch_size, len(shard))`` of its shard, drawn without
+        replacement from the client's stream."""
         part = self.partitions[client_id]
         rng = np.random.default_rng((self._stream(client_id), step))
         take = rng.choice(len(part), size=min(batch_size, len(part)), replace=False)
-        idx = part[take]
+        return part[take]
+
+    def client_batch(self, client_id: int, batch_size: int, step: int) -> dict:
+        idx = self.client_indices(client_id, batch_size, step)
         return {"x": self.base.features[idx], "y": self.base.labels[idx]}
 
     def client_weights(self) -> np.ndarray:

@@ -148,7 +148,11 @@ class FederatedOrchestrator:
 
         # batched engine state (built lazily in _warmup)
         self._agg: Optional[SegmentAggregator] = None
-        self._local_fns: Dict[tuple, Callable] = {}
+        self._local_fns: Dict[bool, Callable] = {}
+        # the sample set's device copy, (host features, host labels,
+        # device features, device labels), and evaluation slices of it
+        self._samples: Optional[tuple] = None
+        self._eval_batches: Dict[int, dict] = {}
 
         # elastic population state: the hierarchy is a versioned run
         # property (mirrors SimulatedEnvironment); the capacity window
@@ -280,60 +284,109 @@ class FederatedOrchestrator:
     # ==================================================================
     # batched engine: vmap'd local steps + per-level segment sums
     # ==================================================================
-    def _collect_batches(self, round_idx: int, ids=None):
-        """Per-client step batches, bucketed by batch shape.
+    def _device_samples(self):
+        """The dataset's fixed sample set on the device, as ``(features,
+        labels)``, when its batches are positions into that set
+        (``client_indices`` over ``base.features`` / ``base.labels``);
+        ``None`` for a dataset that makes its batches step by step.
 
-        Returns [(client_ids, stacked)] where stacked leaves are
-        (C_bucket, local_steps, batch, ...) — identical values to what
-        the loop engine would feed step-by-step. ``ids`` restricts the
-        cohort (the online track trains partial cohorts); ``None``
-        means every client, in id order.
+        Uploaded on first use (the warm-up) and again only when the base
+        arrays themselves change: elastic joiners' shards index into the
+        same set, and a swapped-in dataset over other arrays re-uploads."""
+        base = getattr(self.data, "base", None)
+        features = getattr(base, "features", None)
+        labels = getattr(base, "labels", None)
+        if not hasattr(self.data, "client_indices") or features is None \
+                or labels is None:
+            return None
+        held = self._samples
+        if held is None or held[0] is not features or held[1] is not labels:
+            held = self._samples = (features, labels,
+                                    jax.device_put(features),
+                                    jax.device_put(labels))
+            self._eval_batches = {}
+        return held[2], held[3]
+
+    def _collect_batches(self, round_idx: int, ids=None):
+        """Per-client step inputs, bucketed by batch shape.
+
+        Returns [(client_ids, inputs)]. With the sample set on the device
+        (:meth:`_device_samples`) ``inputs`` is the bucket's int32 table
+        of positions into it, (C_bucket, local_steps, batch); otherwise a
+        dict of stacked batches, (C_bucket, local_steps, batch, ...).
+        Either way the values are the ones the loop engine feeds step by
+        step. ``ids`` restricts the cohort (the online track trains
+        partial cohorts); ``None`` means every client, in id order.
         """
         with tracing.span("round.inputs"):
             if ids is None:
                 ids = range(self.hierarchy.total_clients)
+            first = round_idx * self.local_steps
+            steps = range(first, first + self.local_steps)
+            if self._device_samples() is not None:
+                tables: Dict[int, list] = {}
+                for c in ids:
+                    c = int(c)
+                    rows = [self.data.client_indices(c, self.batch_size, s)
+                            for s in steps]
+                    tables.setdefault(len(rows[0]), []).append((c, rows))
+                return [(np.asarray([c for c, _ in entries], np.int64),
+                         np.array([rows for _, rows in entries], np.int32))
+                        for entries in tables.values()]
             buckets: Dict[tuple, list] = {}
             for c in ids:
                 c = int(c)
-                steps = [self.data.client_batch(
-                    c, self.batch_size, round_idx * self.local_steps + s)
-                    for s in range(self.local_steps)]
+                batches = [self.data.client_batch(c, self.batch_size, s)
+                           for s in steps]
                 sig = tuple(sorted((k, v.shape, str(np.asarray(v).dtype))
-                                   for k, v in steps[0].items()))
-                buckets.setdefault(sig, []).append((c, steps))
+                                   for k, v in batches[0].items()))
+                buckets.setdefault(sig, []).append((c, batches))
             out = []
-            for _sig, entries in buckets.items():
-                ids = np.asarray([c for c, _ in entries], np.int64)
+            for entries in buckets.values():
                 keys = entries[0][1][0].keys()
-                stacked = {k: np.stack([np.stack([np.asarray(st[k])
-                                                  for st in steps])
-                                        for _, steps in entries])
+                stacked = {k: np.stack([np.stack([np.asarray(b[k])
+                                                  for b in batches])
+                                        for _, batches in entries])
                            for k in keys}
-                out.append((ids, stacked))
+                out.append((np.asarray([c for c, _ in entries], np.int64),
+                            stacked))
             return out
 
-    def _local_fn_for(self, sig: tuple) -> Callable:
-        fn = self._local_fns.get(sig)
+    def _local_fn_for(self, indexed: bool) -> Callable:
+        """The jitted ``local_all``; jit compiles it once per bucket
+        shape. ``indexed`` takes ``(params, features, labels, idx)`` and
+        gathers each step's batch from the sample set inside the scan
+        (the set comes in as arguments, never as a constant of the
+        program); otherwise ``(params, batches)``, stacked on the host."""
+        fn = self._local_fns.get(indexed)
         if fn is not None:
             return fn
         loss_fn = self.model.loss_fn
         lr = self.local_lr
 
-        def local_all(params, batches):
-            def per_client(client_batches):
-                def step(p, b):
-                    lval, g = jax.value_and_grad(
-                        lambda q: loss_fn(q, b)[0])(p)
-                    return jax.tree.map(
-                        lambda x, gg: x - lr * gg, p, g), lval
+        def train(params, steps, batch_of):
+            def step(p, s):
+                b = batch_of(s)
+                lval, g = jax.value_and_grad(
+                    lambda q: loss_fn(q, b)[0])(p)
+                return jax.tree.map(
+                    lambda x, gg: x - lr * gg, p, g), lval
 
-                final, losses = jax.lax.scan(step, params, client_batches)
-                return final, losses[-1]
+            final, losses = jax.lax.scan(step, params, steps)
+            return final, losses[-1]
 
-            return jax.vmap(per_client)(batches)
+        if indexed:
+            def local_all(params, features, labels, idx):
+                return jax.vmap(lambda rows: train(
+                    params, rows,
+                    lambda i: {"x": features[i], "y": labels[i]}))(idx)
+        else:
+            def local_all(params, batches):
+                return jax.vmap(lambda client_batches: train(
+                    params, client_batches, lambda b: b))(batches)
 
         fn = jax.jit(local_all, static_argnames=())
-        self._local_fns[sig] = fn
+        self._local_fns[indexed] = fn
         return fn
 
     def _dispatch_local(self, round_idx: int, ids=None):
@@ -342,10 +395,13 @@ class FederatedOrchestrator:
         updated params)], wall seconds until the last call is done)."""
         t0 = time.perf_counter()
         pieces: List[Tuple[np.ndarray, object]] = []
-        for bucket_ids, stacked in self._collect_batches(round_idx, ids):
-            sig = tuple(sorted((k, v.shape[2:], str(v.dtype))
-                               for k, v in stacked.items()))
-            new_p, _ = self._local_fn_for(sig)(self.params, stacked)
+        for bucket_ids, inputs in self._collect_batches(round_idx, ids):
+            indexed = isinstance(inputs, np.ndarray)
+            args = (*self._device_samples(), inputs) if indexed \
+                else (inputs,)
+            new_p, _ = self._local_fn_for(indexed)(self.params, *args)
+            if indexed:
+                tracing.count("round.indexed_calls")
             tracing.count("round.local_calls")
             pieces.append((bucket_ids, new_p))
         with tracing.span("round.wait"):
@@ -530,12 +586,25 @@ class FederatedOrchestrator:
             if hasattr(self.data, "eval_batch"):
                 batch = self.data.eval_batch(n)
             else:
-                base = self.data.base
-                idx = np.arange(min(n, len(base)))
-                batch = {"x": base.features[idx], "y": base.labels[idx]}
+                batch = self._base_eval_batch(n)
             loss, metrics = self._eval(self.params, batch)
             with tracing.span("round.wait"):
                 return float(loss), float(metrics.get("acc", 0.0))
+
+    def _base_eval_batch(self, n: int) -> dict:
+        """The first ``n`` samples of the base set: sliced once from its
+        device copy where there is one, else gathered on the host."""
+        samples = self._device_samples()
+        if samples is None:
+            base = self.data.base
+            idx = np.arange(min(n, len(base)))
+            return {"x": base.features[idx], "y": base.labels[idx]}
+        batch = self._eval_batches.get(n)
+        if batch is None:
+            features, labels = samples
+            batch = self._eval_batches[n] = {"x": features[:n],
+                                             "y": labels[:n]}
+        return batch
 
     # ------------------------------------------------------------------
     def warmup(self) -> None:

@@ -83,15 +83,18 @@ def test_round_engine_local_step_compiles_for_v5e(one_chip):
     spec = get_scenario("paper-fig4")
     assert spec.model == "paper-mlp-1m8"
     orch = spec.make_environment(0).orchestrator
-    [(ids, stacked)] = orch._collect_batches(0)
+    [(ids, idx)] = orch._collect_batches(0)
     assert len(ids) == 10
-    sig = tuple(sorted((k, v.shape[2:], str(v.dtype))
-                       for k, v in stacked.items()))
-    local_all = orch._local_fn_for(sig)
+    # the indexed step: batches gathered from the device-resident set
+    local_all = orch._local_fn_for(True)
     compiled = local_all.lower(_sds(orch.params, one_chip),
-                               _sds(stacked, one_chip)).compile()
+                               *_sds(orch._device_samples(), one_chip),
+                               _sds(idx, one_chip)).compile()
     n_params = sum(x.size for x in jax.tree.leaves(orch.params))
     assert n_params > 1_700_000
+    # the sample set is an argument of the program, not a constant in it
+    assert compiled.memory_analysis().argument_size_in_bytes >= \
+        orch.data.base.features.nbytes
     # 10 stacked f32 copies of the parameters come back
     assert compiled.memory_analysis().output_size_in_bytes >= \
         10 * 4 * n_params

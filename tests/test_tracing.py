@@ -150,7 +150,8 @@ def test_round_opens_every_round_span_once_a_round(emulated, tmp_path):
         assert snap["count"][name] == 2, name
     # the local step's wait and the evaluation's readback, each round
     assert snap["count"]["round.wait"] == 4
-    assert snap["counters"] == {"round.local_calls": 2 * buckets}
+    assert snap["counters"] == {"round.local_calls": 2 * buckets,
+                                "round.indexed_calls": 2 * buckets}
     own, sec = snap["self_seconds"], snap["seconds"]
     # the evaluation's readback is the eval span's child; the local
     # step's wait is the round's
@@ -217,7 +218,7 @@ SNAPSHOT = {
                      "tpd.prologue": 0.6, "tpd.transfer": 1.0,
                      "tpd.wait": 0.2},
     "count": {},
-    "counters": {"round.local_calls": 12},
+    "counters": {"round.local_calls": 12, "round.indexed_calls": 9},
 }
 UNITS = 4
 READERS = {
@@ -227,6 +228,7 @@ READERS = {
     "round.eval_host_ms": 0.08 / UNITS * 1e3,
     "round.wait_ms": 0.4 / UNITS * 1e3,
     "round.local_calls": 12 / UNITS,
+    "round.indexed_share": 9 / 12,
     "search.update_ms": 1.0 / UNITS * 1e3,
     "search.prologue_ms": 0.6 / UNITS * 1e3,
     "search.transfer_ms": 1.0 / UNITS * 1e3,
@@ -258,3 +260,12 @@ def test_metric_is_none_without_its_span(name, program, monkeypatch):
         monkeypatch.setitem(sys.modules, "repro.utils.tracing", None)
         monkeypatch.delattr("repro.utils.tracing", raising=False)
     assert _reader(name)({"stats": {"units": UNITS}}) is None
+
+
+def test_indexed_share_is_none_without_the_indexed_counter(monkeypatch):
+    """A program that counts ``local_all`` dispatches but has no indexed
+    input path reports no share."""
+    snap = dict(SNAPSHOT, counters={"round.local_calls": 12})
+    monkeypatch.setattr(tracing, "snapshot", lambda: snap)
+    assert _reader("round.indexed_share")({"stats": {"units": UNITS}}) \
+        is None
