@@ -12,10 +12,13 @@ from repro.configs.base import (
     SHAPES,
     TRAIN_4K,
     FLConfig,
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     ShapeConfig,
+    YarnScaling,
 )
+from repro.configs.deepseek_v2_lite import CONFIG as _dsv2_lite
 from repro.configs.granite_8b import CONFIG as _granite_8b
 from repro.configs.granite_moe_1b_a400m import CONFIG as _granite_moe
 from repro.configs.llava_next_mistral_7b import CONFIG as _llava
@@ -43,6 +46,7 @@ _REGISTRY = {
         _stablelm16b,
         _paper_mlp,
         _mlp_smoke,
+        _dsv2_lite,
     )
 }
 
@@ -78,7 +82,8 @@ def get_shape(name: str) -> ShapeConfig:
 
 
 __all__ = [
-    "ModelConfig", "MoEConfig", "ShapeConfig", "FLConfig",
+    "ModelConfig", "MoEConfig", "MLAConfig", "YarnScaling", "ShapeConfig",
+    "FLConfig",
     "SHAPES", "TRAIN_4K", "PREFILL_32K", "DECODE_32K", "LONG_500K",
     "ASSIGNED", "get_config", "get_shape", "list_configs",
 ]

@@ -19,10 +19,47 @@ class MoEConfig:
     n_experts: int
     top_k: int
     d_ff_expert: int
-    # router load-balance auxiliary loss weight (Switch-style)
+    # router load-balance auxiliary loss weight (Switch-style; on the
+    # dropless path DeepSeek's sequence-level loss, summed over layers)
     router_aux_weight: float = 0.01
-    # capacity factor used to bound expert buffers in the dense-dispatch path
-    capacity_factor: float = 1.25
+    # capacity factor used to bound expert buffers in the dense-dispatch
+    # path; None = dropless (DeepSeekMoE): every routed token-expert pair
+    # is computed, as a grouped matmul over the held experts, and the
+    # top-k weights are neither renormalized nor scaled (DeepSeek-V2)
+    capacity_factor: Optional[float] = 1.25
+    # shared experts applied to every token, as one gated MLP of width
+    # n_shared_experts * d_ff_expert
+    n_shared_experts: int = 0
+    # expert parallelism as seen from one chip: this chip holds experts
+    # [expert_shard * n_held, (expert_shard + 1) * n_held) of n_experts;
+    # the router still scores all n_experts. None = every expert held.
+    n_held: Optional[int] = None
+    expert_shard: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
+    a low-rank joint KV latent with decoupled rotary keys. No query LoRA."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (the ``rope_scaling`` block of a HF config)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -37,6 +74,11 @@ class ModelConfig:
     vocab_size: int
     head_dim: Optional[int] = None  # default: d_model // n_heads
     moe: Optional[MoEConfig] = None
+    # leading dense layers before the MoE stack (DeepSeek's
+    # first_k_dense_replace); they use the dense FFN of width d_ff
+    first_k_dense: int = 0
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnScaling] = None
 
     # --- attention variants -------------------------------------------------
     rope_theta: float = 10_000.0
@@ -100,11 +142,22 @@ class ModelConfig:
         n_kv = max(1, min(self.n_kv_heads, n_heads))
         moe = None
         if self.moe is not None:
-            moe = MoEConfig(n_experts=min(self.moe.n_experts, 4),
-                            top_k=min(self.moe.top_k, 2),
-                            d_ff_expert=64)
+            n_experts = min(self.moe.n_experts, 4)
+            moe = dataclasses.replace(
+                self.moe, n_experts=n_experts,
+                top_k=min(self.moe.top_k, 2), d_ff_expert=64,
+                n_held=None if self.moe.n_held is None
+                else min(self.moe.n_held, n_experts),
+                expert_shard=0)
+        mla = None
+        if self.mla is not None:
+            mla = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                            qk_rope_head_dim=8, v_head_dim=16)
         return self.replace(
-            n_layers=2,
+            # the leading dense layers plus two of the repeated ones
+            n_layers=min(self.first_k_dense, 1) + 2,
+            first_k_dense=min(self.first_k_dense, 1),
+            mla=mla,
             d_model=d_model,
             n_heads=n_heads,
             n_kv_heads=n_kv,

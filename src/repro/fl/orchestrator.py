@@ -23,6 +23,12 @@ Two round engines drive the same semantics:
   ``segment_sum`` per tree level, driven by ``Hierarchy.round_plan``
   index tables. This is what scales the emulation past a few dozen
   clients (benchmarks/bench_round_engine.py sweeps 16 -> 256).
+  When the device cannot hold every client's training state at once (a
+  client model at published widths), the same engine trains the clients
+  in chunks, cluster by cluster down the tree, and folds each chunk's
+  weighted updates into one accumulator per level as it goes
+  (:meth:`FederatedOrchestrator._round_chunked`); the chunk size comes
+  from the model's bytes and the device's memory.
 * ``engine='loop'``: the original per-client / per-cluster dispatch.
   Its wall-clock timing is per-cluster-faithful (the docker-faithful
   'measured' mode on a quiet box); the batched engine necessarily
@@ -149,6 +155,12 @@ class FederatedOrchestrator:
         # batched engine state (built lazily in _warmup)
         self._agg: Optional[SegmentAggregator] = None
         self._local_fns: Dict[bool, Callable] = {}
+        self._folds: Optional[tuple] = None
+        self._chunk_for: Optional[tuple] = None   # (tree, chunk)
+        # a model whose loss does not batch over clients trains them one
+        # after another; local_all then also returns the token-expert
+        # pairs its held experts computed (``moe_held``), if it has any
+        self._per_client = not getattr(model, "batches_over_clients", True)
         # the sample set's device copy, (host features, host labels,
         # device features, device labels), and evaluation slices of it
         self._samples: Optional[tuple] = None
@@ -363,26 +375,42 @@ class FederatedOrchestrator:
             return fn
         loss_fn = self.model.loss_fn
         lr = self.local_lr
+        per_client = self._per_client
 
         def train(params, steps, batch_of):
             def step(p, s):
                 b = batch_of(s)
-                lval, g = jax.value_and_grad(
-                    lambda q: loss_fn(q, b)[0])(p)
+                if per_client:
+                    (lval, m), g = jax.value_and_grad(
+                        lambda q: loss_fn(q, b), has_aux=True)(p)
+                    out = (lval, m.get("moe_held", jnp.int32(0)))
+                else:
+                    lval, g = jax.value_and_grad(
+                        lambda q: loss_fn(q, b)[0])(p)
+                    out = lval
                 return jax.tree.map(
-                    lambda x, gg: x - lr * gg, p, g), lval
+                    lambda x, gg: x - lr * gg, p, g), out
 
-            final, losses = jax.lax.scan(step, params, steps)
-            return final, losses[-1]
+            # unrolled, each step's gradient is freed before the next: a
+            # loop would keep the weights twice in its state (a model at
+            # published widths needs that room)
+            final, outs = jax.lax.scan(step, params, steps,
+                                       unroll=per_client)
+            if per_client:
+                return final, (outs[0][-1], jnp.sum(outs[1]))
+            return final, outs[-1]
 
+        # clients ride a vmap, unless the model does not batch over them
+        over_clients = jax.vmap if not per_client else \
+            (lambda f: lambda xs: jax.lax.map(f, xs))
         if indexed:
             def local_all(params, features, labels, idx):
-                return jax.vmap(lambda rows: train(
+                return over_clients(lambda rows: train(
                     params, rows,
                     lambda i: {"x": features[i], "y": labels[i]}))(idx)
         else:
             def local_all(params, batches):
-                return jax.vmap(lambda client_batches: train(
+                return over_clients(lambda client_batches: train(
                     params, client_batches, lambda b: b))(batches)
 
         fn = jax.jit(local_all, static_argnames=())
@@ -395,18 +423,34 @@ class FederatedOrchestrator:
         updated params)], wall seconds until the last call is done)."""
         t0 = time.perf_counter()
         pieces: List[Tuple[np.ndarray, object]] = []
+        outs = []
         for bucket_ids, inputs in self._collect_batches(round_idx, ids):
-            indexed = isinstance(inputs, np.ndarray)
-            args = (*self._device_samples(), inputs) if indexed \
-                else (inputs,)
-            new_p, _ = self._local_fn_for(indexed)(self.params, *args)
-            if indexed:
-                tracing.count("round.indexed_calls")
-            tracing.count("round.local_calls")
+            new_p, out = self._local_call(inputs)
             pieces.append((bucket_ids, new_p))
+            outs.append(out)
         with tracing.span("round.wait"):
             jax.block_until_ready(jax.tree.leaves(pieces[-1][1])[0])
+        self._count_held(outs)
         return pieces, time.perf_counter() - t0
+
+    def _local_call(self, inputs):
+        """One ``local_all`` dispatch over a bucket (or a chunk of one):
+        ``(updated params stacked over its clients, its outputs)``."""
+        indexed = isinstance(inputs, np.ndarray)
+        args = (*self._device_samples(), inputs) if indexed else (inputs,)
+        new_p, out = self._local_fn_for(indexed)(self.params, *args)
+        if indexed:
+            tracing.count("round.indexed_calls")
+        tracing.count("round.local_calls")
+        return new_p, out
+
+    def _count_held(self, outs) -> None:
+        """Token-expert pairs the held experts computed, read from
+        ``local_all`` outputs that are already done (only while the
+        profiler records, so an untraced round transfers nothing)."""
+        if self._per_client and tracing.recording():
+            tracing.count("moe.held_assignments",
+                          int(sum(np.sum(np.asarray(o[1])) for o in outs)))
 
     def _train_all_batched(self, round_idx: int):
         """All clients' local training. Returns (stacked_updates (C,...),
@@ -442,61 +486,201 @@ class FederatedOrchestrator:
         clusters by payload share before the pspeed/comm composition.
         """
         with tracing.span("round.merge"):
-            h = self.hierarchy
-            plan = h.round_plan(placement)
-            mds = self.clients.mdatasize
-            depth = h.depth
-
-            def level_time(lp, cluster_dt, idx, raw_loads) -> float:
-                """pspeed/comm/noise composition, vectorized per level (one
-                rng draw per cluster, same stream order as the loop engine)."""
-                ts = (cluster_dt / self.clients.pspeed[lp.hosts]
-                      + self.comm_latency * lp.n_parts)
-                if self.rng_noise:
-                    ts = ts * (1.0 + self.rng.normal(0, self.rng_noise,
-                                                     size=lp.n_clusters))
-                if self._trace is not None:
-                    level = depth - 1 - idx  # plan levels are deepest first
-                    start = h.level_starts[level]
-                    self._trace["levels"].append({
-                        "level": level,
-                        "slots": list(range(start, start + lp.n_clusters)),
-                        "hosts": lp.hosts.tolist(),
-                        "loads": np.asarray(raw_loads, np.float64).tolist(),
-                        "n_parts": lp.n_parts.tolist(),
-                        "delays": np.asarray(ts, np.float64).tolist()})
-                return float(ts.max())
-
+            plan = self.hierarchy.round_plan(placement)
             if self.timing == "deterministic":
                 # charge eq. 6 analytically; run the whole aggregation as
                 # ONE jit call (no per-level host syncs needed)
                 new_global = self._agg.aggregate_fused(
                     stacked_updates, self.weights, plan)
-                total = 0.0
-                for idx, lp in enumerate(plan.levels):
-                    loads = np.zeros(lp.n_clusters)
-                    np.add.at(loads, lp.seg, mds[lp.member_clients])
-                    total += level_time(lp, loads / self.EQ6_PAYLOAD_SCALE,
-                                        idx, loads)
-                return new_global, total
+                return new_global, self._charge_levels(plan)
 
             weighted = self._agg.weighted(stacked_updates, self.weights)
-            total = 0.0
+            walls = []
             vals = None
-            for idx, lp in enumerate(plan.levels):
+            for idx in range(len(plan.levels)):
                 t0 = time.perf_counter()
                 vals = self._agg.run_level(idx, weighted, vals, plan)
                 jax.block_until_ready(jax.tree.leaves(vals)[0])
-                wall = time.perf_counter() - t0
-                loads = np.zeros(lp.n_clusters)
-                np.add.at(loads, lp.seg, mds[lp.member_clients])
-                total += level_time(lp, wall * loads / max(loads.sum(), 1e-12),
-                                    idx, loads)
-            return jax.tree.map(lambda x: x[0], vals), total
+                walls.append(time.perf_counter() - t0)
+            return jax.tree.map(lambda x: x[0], vals), \
+                self._charge_levels(plan, walls)
+
+    def _client_chunk(self) -> int:
+        """Clients whose local training runs at once. Every client fits
+        unless the device reports a memory limit that the global model,
+        one accumulator per tree level and three model-sized buffers per
+        client (its weights, their gradient and one temporary), within
+        three quarters of the limit, cannot hold; then as many as fit,
+        at least one."""
+        C = self.hierarchy.total_clients
+        limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            return C
+        model = sum(x.nbytes for x in jax.tree.leaves(self.params))
+        free = 0.75 * limit - (1 + self.hierarchy.depth) * model
+        return int(min(C, max(1, free // (3 * model))))
+
+    def _chunk(self) -> int:
+        """``_client_chunk`` of the current tree, asked once (at warm-up)
+        and again only when the tree changes, not every round."""
+        tree = (self.hierarchy.total_clients, self.hierarchy.depth)
+        if self._chunk_for is None or self._chunk_for[0] != tree:
+            self._chunk_for = (tree, self._client_chunk())
+        return self._chunk_for[1]
+
+    def _fold_fns(self) -> tuple:
+        """The chunked round's jitted folds, each writing over the
+        accumulator it is given: ``fold_start`` (w * u[i]), ``fold_add``
+        (acc + w * u[i]) and ``fold_merge`` (acc + child), the weighting
+        as ``SegmentAggregator``'s."""
+        if self._folds is None:
+            def fold_start(stack, i, w):
+                return jax.tree.map(
+                    lambda x: x[i] * w.astype(x.dtype), stack)
+
+            def fold_add(acc, stack, i, w):
+                return jax.tree.map(
+                    lambda a, x: a + x[i] * w.astype(x.dtype), acc, stack)
+
+            def fold_merge(acc, child):
+                return jax.tree.map(jnp.add, acc, child)
+
+            self._folds = (
+                jax.jit(fold_start, static_argnames=()),
+                jax.jit(fold_add, static_argnames=(), donate_argnums=(0,)),
+                jax.jit(fold_merge, static_argnames=(), donate_argnums=(0,)))
+        return self._folds
+
+    def _visit_order(self, placement: np.ndarray) -> list:
+        """The tree walked depth first, as a list of ``("client", c,
+        slot)`` (fold client c's update into slot's accumulator) and
+        ``("merge", child, slot)`` steps. Every cluster folds its host
+        first, then its children in order: ``SegmentAggregator``'s
+        ``[host, children...]``."""
+        h = self.hierarchy
+        trainers = h.trainer_assignment(placement)
+        leaf0 = h.level_starts[h.depth - 1]
+        steps: list = []
+
+        def visit(slot: int) -> None:
+            steps.append(("client", int(placement[slot]), slot))
+            kids = h.children_slots(slot)
+            for k in kids:
+                visit(k)
+                steps.append(("merge", k, slot))
+            if not kids:
+                steps.extend(("client", int(t), slot)
+                             for t in trainers[slot - leaf0])
+        visit(0)
+        return steps
+
+    def _round_chunked(self, r: int, placement: np.ndarray, chunk: int):
+        """Train the clients ``chunk`` at a time in the tree's depth-first
+        order and fold each one's weighted update into its cluster's
+        accumulator; a finished cluster folds into its parent's. One
+        accumulator per level is live, plus one chunk's training state,
+        whatever the number of clients. Returns the new global params."""
+        fold_start, fold_add, fold_merge = self._fold_fns()
+        where = {}
+        buckets = self._collect_batches(r)
+        for b, (ids, _) in enumerate(buckets):
+            for row, c in enumerate(ids.tolist()):
+                where[c] = (b, row)
+        steps = self._visit_order(placement)
+        order = [c for kind, c, _ in steps if kind == "client"]
+        acc: dict = {}
+        held: Optional[dict] = None   # client -> (chunk's updates, row)
+        outs = []
+        pos = 0
+        for kind, c, slot in steps:
+            if kind == "merge":
+                with tracing.span("round.chunk"):
+                    acc[slot] = fold_merge(acc[slot], acc.pop(c))
+                continue
+            if held is None:
+                # the next chunk: consecutive clients of one input bucket
+                b = where[order[pos]][0]
+                ids = []
+                while pos < len(order) and len(ids) < chunk and \
+                        where[order[pos]][0] == b:
+                    ids.append(order[pos])
+                    pos += 1
+                with tracing.span("round.chunk"):
+                    rows = np.asarray([where[i][1] for i in ids])
+                    inputs = buckets[b][1]
+                    inputs = inputs[rows] if isinstance(inputs, np.ndarray) \
+                        else {k: v[rows] for k, v in inputs.items()}
+                    stack, out = self._local_call(inputs)
+                    outs.append(out)
+                    tracing.count("round.client_chunks")
+                held = {i: (stack, j) for j, i in enumerate(ids)}
+            stack, j = held[c]
+            with tracing.span("round.chunk"):
+                w = jnp.float32(self.weights[c])
+                acc[slot] = fold_start(stack, j, w) if slot not in acc \
+                    else fold_add(acc[slot], stack, j, w)
+            if c == ids[-1]:
+                held = stack = None     # the chunk's updates are folded
+                with tracing.span("round.wait"):
+                    jax.block_until_ready(jax.tree.leaves(acc[slot])[0])
+        self._count_held(outs)
+        return acc[0]
+
+    def _charge_levels(self, plan, walls=None) -> float:
+        """eq. 7 over the plan's levels: the sum of each level's slowest
+        cluster. Deterministic timing charges eq. 6 from the plan's
+        ACTUAL member payloads (same formula, same rng stream as the
+        loop engine); measured timing splits each level's wall seconds
+        (``walls``) across its clusters by payload share."""
+        h = self.hierarchy
+        mds = self.clients.mdatasize
+        total = 0.0
+        for idx, lp in enumerate(plan.levels):
+            loads = np.zeros(lp.n_clusters)
+            np.add.at(loads, lp.seg, mds[lp.member_clients])
+            if walls is None:
+                cluster_dt = loads / self.EQ6_PAYLOAD_SCALE
+            else:
+                cluster_dt = walls[idx] * loads / max(loads.sum(), 1e-12)
+            # pspeed/comm/noise composition, vectorized per level (one rng
+            # draw per cluster, same stream order as the loop engine)
+            ts = (cluster_dt / self.clients.pspeed[lp.hosts]
+                  + self.comm_latency * lp.n_parts)
+            if self.rng_noise:
+                ts = ts * (1.0 + self.rng.normal(0, self.rng_noise,
+                                                 size=lp.n_clusters))
+            if self._trace is not None:
+                level = h.depth - 1 - idx  # plan levels are deepest first
+                start = h.level_starts[level]
+                self._trace["levels"].append({
+                    "level": level,
+                    "slots": list(range(start, start + lp.n_clusters)),
+                    "hosts": lp.hosts.tolist(),
+                    "loads": np.asarray(loads, np.float64).tolist(),
+                    "n_parts": lp.n_parts.tolist(),
+                    "delays": np.asarray(ts, np.float64).tolist()})
+            total += float(ts.max())
+        return total
 
     def _round_batched(self, r: int, placement: np.ndarray):
         if self._agg is None:
             self._agg = SegmentAggregator(self.hierarchy)
+        chunk = self._chunk()
+        if chunk < self.hierarchy.total_clients:
+            if self.timing != "deterministic":
+                raise ValueError(
+                    "a round trained in client chunks charges eq. 6 "
+                    "analytically and needs timing='deterministic'")
+            new_params = self._round_chunked(r, placement, chunk)
+            train_times = float(self.local_steps) / self.clients.pspeed
+            if self._trace is not None:
+                self._trace["train"] = {
+                    "clients": list(range(self.hierarchy.total_clients)),
+                    "times": np.asarray(train_times, np.float64).tolist()}
+            with tracing.span("round.merge"):
+                agg_time = self._charge_levels(
+                    self.hierarchy.round_plan(placement))
+            return new_params, float(np.max(train_times)), agg_time
         stacked_updates, train_times = self._train_all_batched(r)
         if self._trace is not None:
             self._trace["train"] = {
@@ -589,7 +773,11 @@ class FederatedOrchestrator:
                 batch = self._base_eval_batch(n)
             loss, metrics = self._eval(self.params, batch)
             with tracing.span("round.wait"):
-                return float(loss), float(metrics.get("acc", 0.0))
+                out = float(loss), float(metrics.get("acc", 0.0))
+            if "moe_held" in metrics and tracing.recording():
+                tracing.count("moe.eval_held_assignments",
+                              int(metrics["moe_held"]))
+            return out
 
     def _base_eval_batch(self, n: int) -> dict:
         """The first ``n`` samples of the base set: sliced once from its
@@ -613,11 +801,16 @@ class FederatedOrchestrator:
         if self.engine == "batched":
             if self._agg is None:
                 self._agg = SegmentAggregator(self.hierarchy)
+            placement = np.arange(self.hierarchy.dimensions)
+            chunk = self._chunk()
+            if chunk < self.hierarchy.total_clients:
+                self._round_chunked(0, placement, chunk)
+                self._evaluate()
+                return
             stacked, _ = self._train_all_batched(0)
             noise, self.rng_noise = self.rng_noise, 0.0  # keep rng stream
             try:
-                self._agg_batched(stacked,
-                                  np.arange(self.hierarchy.dimensions))
+                self._agg_batched(stacked, placement)
             finally:
                 self.rng_noise = noise
             self._evaluate()

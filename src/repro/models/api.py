@@ -35,6 +35,10 @@ class Model:
     spec_rule: Optional[Callable] = None
     # decode-state sharding rule: (path str, shape) -> PartitionSpec
     state_spec_rule: Optional[Callable] = None
+    # False when ``loss_fn`` cannot be vmapped over clients (a layer runs
+    # a kernel that does not batch, the grouped expert matmul); such a
+    # model trains its clients one after another
+    batches_over_clients: bool = True
 
     # ------------------------------------------------------------------
     def param_shapes(self, rng=None):

@@ -18,7 +18,9 @@ Design notes (TPU adaptation):
   for attention architectures.
 * GQA is handled by folding query heads into groups over the kv heads.
 
-Shapes: q (B, S, Hq, hd); k, v (B, T, Hkv, hd). All softmax math in f32.
+Shapes: q (B, S, Hq, hd); k (B, T, Hkv, hd); v (B, T, Hkv, hd_v), where
+the causal path lets hd_v differ from hd (latent attention). All softmax
+math in f32.
 """
 from __future__ import annotations
 
@@ -74,7 +76,7 @@ def _attend_dense_core(q, k, v, mask: Optional[jnp.ndarray], scale: float
         p = jnp.where(mask[None, :, None, None, :], p, 0.0)
     denom = jnp.sum(p, axis=-1)
     out = jnp.einsum("bqhgk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return _Partial(out=out.reshape(b, sq, hkv * g, hd),
+    return _Partial(out=out.reshape(b, sq, hkv * g, v.shape[-1]),
                     m=m.reshape(b, sq, hkv * g),
                     denom=denom.reshape(b, sq, hkv * g))
 

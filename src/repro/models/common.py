@@ -53,16 +53,46 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, head_dim); positions: broadcastable to (..., S)."""
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               freqs: Optional[jnp.ndarray] = None,
+               mscale: float = 1.0) -> jnp.ndarray:
+    """x: (..., S, H, head_dim); positions: broadcastable to (..., S).
+    Dim i pairs with dim i + head_dim/2. ``freqs`` replaces the plain
+    inverse frequencies (YaRN), ``mscale`` scales cos and sin."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)                    # (half,)
+    if freqs is None:
+        freqs = rope_frequencies(head_dim, theta)                # (half,)
     angles = positions[..., None].astype(jnp.float32) * freqs    # (..., S, half)
-    cos = jnp.cos(angles)[..., None, :]                          # (..., S, 1, half)
-    sin = jnp.sin(angles)[..., None, :]
+    cos = jnp.cos(angles)[..., None, :] * mscale                 # (..., S, 1, half)
+    sin = jnp.sin(angles)[..., None, :] * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, scaling) -> jnp.ndarray:
+    """YaRN's blend of interpolated and original inverse frequencies
+    (``scaling``: a ``configs.base.YarnScaling``): dims that rotate fewer
+    than ``beta_slow`` times over the original context are divided by
+    ``factor``, those over ``beta_fast`` kept, a linear ramp between."""
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(scaling.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_frequencies(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / scaling.factor * (1.0 - keep) + extra * keep
 
 
 # --------------------------------------------------------------------------

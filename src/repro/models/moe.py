@@ -23,6 +23,14 @@ accounting the analysis needs.
 
 On a single device (CPU smoke tests) the same math runs without the
 shard_map wrapper (E_local == E, no psum).
+
+A config with ``capacity_factor=None`` takes the dropless held-expert
+path instead (:func:`held_moe_ffn`, DeepSeekMoE): the router scores all
+``n_experts``, the layer holds ``n_held`` of them, and computes every
+token-expert pair routed to a held expert as a grouped matmul over the
+held experts (tokens sorted by expert, no capacity), plus the shared
+experts. On one chip of an expert-parallel deployment this is the
+chip's part of the layer's output, without the exchange.
 """
 from __future__ import annotations
 
@@ -34,19 +42,23 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
-from repro.models.common import dense_init
+from repro.models.common import dense_init, init_swiglu, swiglu
 from repro.models.sharding import ShardingPolicy
 
 
 def init_moe(key, d_model: int, cfg: MoEConfig, dtype) -> dict:
-    kr, k1, k2, k3 = jax.random.split(key, 4)
-    e, f = cfg.n_experts, cfg.d_ff_expert
-    return {
-        "router": dense_init(kr, (d_model, e), jnp.float32),
+    kr, k1, k2, k3, ks = jax.random.split(key, 5)
+    e, f = cfg.held, cfg.d_ff_expert
+    params = {
+        "router": dense_init(kr, (d_model, cfg.n_experts), jnp.float32),
         "w_gate": dense_init(k1, (e, d_model, f), dtype),
         "w_up": dense_init(k2, (e, d_model, f), dtype),
         "w_down": dense_init(k3, (e, f, d_model), dtype),
     }
+    if cfg.n_shared_experts:
+        params["shared"] = init_swiglu(
+            ks, d_model, cfg.n_shared_experts * f, dtype)
+    return params
 
 
 def _route(x2d: jnp.ndarray, router: jnp.ndarray, top_k: int):
@@ -195,6 +207,116 @@ def moe_ffn(params: dict, x: jnp.ndarray, cfg: MoEConfig,
     )(x2d, gates.astype(x.dtype), params["w_gate"], params["w_up"],
       params["w_down"])
     return out2d.reshape(b, s, d).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless held-expert path (DeepSeekMoE)
+# ---------------------------------------------------------------------------
+
+def route_topk(x2d: jnp.ndarray, router: jnp.ndarray, cfg: MoEConfig):
+    """Softmax scoring over every expert and greedy top-k, in float32.
+
+    Returns ``(probs (T, E), weights (T, k), experts (T, k))``; the
+    weights are the top-k probabilities as they are, neither renormalized
+    nor scaled (DeepSeek-V2's ``norm_topk_prob`` false,
+    ``routed_scaling_factor`` 1)."""
+    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_i = jax.lax.top_k(probs, cfg.top_k)
+    return probs, top_w, top_i
+
+
+def seq_balance_loss(probs: jnp.ndarray, top_i: jnp.ndarray,
+                     n_seqs: int) -> jnp.ndarray:
+    """DeepSeek-V2's sequence-level balance loss (§2.2.3), unweighted:
+    per sequence, sum_e f_e * P_e with f_e the expert's share of the
+    sequence's top-k picks times E/k and P_e its mean probability;
+    averaged over the sequences."""
+    t, e = probs.shape
+    k = top_i.shape[-1]
+    s = t // n_seqs
+    picks = jax.nn.one_hot(top_i.reshape(n_seqs, s * k), e,
+                           dtype=jnp.float32).sum(axis=1)     # (B, E)
+    f = picks / (s * k / e)
+    p = probs.reshape(n_seqs, s, e).mean(axis=1)
+    return jnp.mean(jnp.sum(f * p, axis=-1))
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> tuple:
+    """Tiles of the TPU grouped matmul: 512 rows, the contracted dim in
+    512s (whole when it is not a multiple), the output dim whole up to
+    1,536 columns, else in 512s."""
+    tm = 512 if m % 512 == 0 else 128
+    tk = 512 if k % 512 == 0 else k
+    tn = n if n <= 1536 else 512
+    return tm, tk, tn
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
+                   sizes: jnp.ndarray) -> jnp.ndarray:
+    """``lhs`` (M, K) rows sorted by group, ``rhs`` (G, K, N), ``sizes``
+    (G + 1,) rows per group with the last entry counting the rows that
+    belong to no held group (they sit at the end). Returns (M, N) in
+    ``lhs``'s dtype, accumulated in float32: each held group's rows times
+    its matrix, zeros after.
+
+    On a TPU this is the megablox ``gmm`` kernel (its backward is
+    ``gmm``/``tgmm``), which computes only the held groups' tiles;
+    elsewhere ``lax.ragged_dot``."""
+    if jax.default_backend() == "tpu":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        return gmm(lhs, rhs, sizes, lhs.dtype, _gmm_tiling)
+    # rows past the held groups are left undefined by ragged_dot (and by
+    # its transpose): zero them on the way in and out
+    valid = (jnp.arange(lhs.shape[0]) < jnp.sum(sizes[:-1]))[:, None]
+    out = jax.lax.ragged_dot(jnp.where(valid, lhs, 0), rhs, sizes[:-1],
+                             preferred_element_type=jnp.float32)
+    return jnp.where(valid, out, 0.0).astype(lhs.dtype)
+
+
+def held_experts(x2d: jnp.ndarray, top_w: jnp.ndarray, top_i: jnp.ndarray,
+                 params: dict, cfg: MoEConfig):
+    """The held experts' gated outputs for the token-expert pairs routed
+    to them, weighted by their gates and summed per token; no pair is
+    dropped. Returns ``(out (T, D) float32, pairs computed)``."""
+    t, d = x2d.shape
+    k, n = top_i.shape[-1], cfg.held
+    local = (top_i - cfg.expert_shard * n).reshape(-1)          # (T*k,)
+    held = (local >= 0) & (local < n)
+    key = jnp.where(held, local, n)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=n + 1).astype(jnp.int32)
+    rows = order // k                                           # token ids
+    xs = x2d[rows]
+    dt = x2d.dtype
+    h = jax.nn.silu(grouped_matmul(xs, params["w_gate"].astype(dt), sizes))
+    h = h * grouped_matmul(xs, params["w_up"].astype(dt), sizes)
+    y = grouped_matmul(h, params["w_down"].astype(dt), sizes)
+    w = jnp.where(held, top_w.reshape(-1), 0.0)[order]
+    out = jnp.zeros((t, d), jnp.float32).at[rows].add(
+        y.astype(jnp.float32) * w[:, None])
+    return out, jnp.sum(sizes[:n])
+
+
+def held_moe_ffn(params: dict, x: jnp.ndarray, cfg: MoEConfig,
+                 mask: Optional[jnp.ndarray] = None):
+    """x: (B, S, D) -> (out (B, S, D), sequence-level balance loss,
+    pairs computed on the held experts). Pad positions (``mask`` False)
+    are routed nowhere."""
+    b, s, d = x.shape
+    x2d = x.reshape(b * s, d)
+    probs, top_w, top_i = route_topk(x2d, params["router"], cfg)
+    aux = seq_balance_loss(probs, top_i, b)
+    if mask is not None:
+        real = jnp.broadcast_to(mask[None, :], (b, s)).reshape(b * s)
+        top_i = jnp.where(real[:, None], top_i, cfg.n_experts)
+    out, pairs = held_experts(x2d, top_w, top_i, params, cfg)
+    if "shared" in params:
+        shared = jax.tree.map(lambda w: w.astype(x2d.dtype), params["shared"])
+        out = out + swiglu(shared, x2d).astype(jnp.float32)
+    return out.reshape(b, s, d).astype(x.dtype), aux, pairs
 
 
 def moe_spec(path: str, shape, policy: ShardingPolicy,

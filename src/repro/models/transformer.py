@@ -3,7 +3,10 @@
 One block implementation serves all three (the MoE family swaps the FFN
 for the expert-parallel ``moe_ffn``; the VLM family prepends stub patch
 embeddings and pads the sequence to a power of two so the exact-FLOP
-causal decomposition applies).
+causal decomposition applies). A config with ``mla`` uses multi-head
+latent attention with YaRN rotary keys, and ``first_k_dense`` leading
+dense layers run before the MoE stack (DeepSeek-V2); such a model is
+built for training only (no prefill or decode).
 
 Layers are stacked and driven by ``lax.scan`` so the HLO contains one
 layer body regardless of depth — Qwen3's 94 layers lower in seconds, and
@@ -20,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_lib, common
 from repro.models.api import Model
-from repro.models.moe import init_moe, moe_ffn, moe_spec
+from repro.models.moe import held_moe_ffn, init_moe, moe_ffn, moe_spec
 from repro.models.sharding import UNSHARDED, ShardingPolicy, shard_hint
 
 
@@ -29,6 +32,8 @@ from repro.models.sharding import UNSHARDED, ShardingPolicy, shard_hint
 # --------------------------------------------------------------------------
 
 def _init_attn(key, cfg: ModelConfig, dtype) -> dict:
+    if cfg.mla is not None:
+        return _init_mla(key, cfg, dtype)
     kq, kk, kv, ko = jax.random.split(key, 4)
     hd = cfg.resolved_head_dim
     return {
@@ -39,14 +44,30 @@ def _init_attn(key, cfg: ModelConfig, dtype) -> dict:
     }
 
 
-def _init_layer(key, cfg: ModelConfig, dtype) -> dict:
+def _init_mla(key, cfg: ModelConfig, dtype) -> dict:
+    m, h = cfg.mla, cfg.n_heads
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+    return {
+        "wq": common.dense_init(kq, (cfg.d_model, h * qk), dtype),
+        "wkv_a": common.dense_init(
+            ka, (cfg.d_model, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
+        "kv_norm": common.init_rmsnorm(m.kv_lora_rank, dtype),
+        "wkv_b": common.dense_init(
+            kb, (m.kv_lora_rank, h * (m.qk_nope_head_dim + m.v_head_dim)),
+            dtype),
+        "wo": common.dense_init(ko, (h * m.v_head_dim, cfg.d_model), dtype),
+    }
+
+
+def _init_layer(key, cfg: ModelConfig, dtype, dense: bool = False) -> dict:
     ka, kf = jax.random.split(key)
     layer = {
         "ln1": common.init_rmsnorm(cfg.d_model, dtype),
         "ln2": common.init_rmsnorm(cfg.d_model, dtype),
         "attn": _init_attn(ka, cfg, dtype),
     }
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         layer["moe"] = init_moe(kf, cfg.d_model, cfg.moe, dtype)
     else:
         layer["ffn"] = common.init_swiglu(kf, cfg.d_model, cfg.d_ff, dtype)
@@ -57,12 +78,18 @@ def init_decoder_params(rng, cfg: ModelConfig) -> dict:
     dtype = jnp.dtype(cfg.param_dtype)
     k_emb, k_layers, k_out = jax.random.split(rng, 3)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    layers = jax.vmap(lambda k: _init_layer(k, cfg, dtype))(layer_keys)
+    n_dense = cfg.first_k_dense
+    layers = jax.vmap(lambda k: _init_layer(k, cfg, dtype))(
+        layer_keys[n_dense:])
     params = {
         "embed": common.init_embedding(k_emb, cfg.padded_vocab, cfg.d_model, dtype),
         "layers": layers,
         "ln_f": common.init_rmsnorm(cfg.d_model, dtype),
     }
+    if n_dense:
+        params["dense_layers"] = jax.vmap(
+            lambda k: _init_layer(k, cfg, dtype, dense=True))(
+                layer_keys[:n_dense])
     if not cfg.tie_embeddings:
         params["lm_head"] = common.init_unembed(
             k_out, cfg.padded_vocab, cfg.d_model, dtype)
@@ -97,14 +124,70 @@ def attention_block(layer_attn: dict, x: jnp.ndarray, cfg: ModelConfig,
     return jnp.einsum("bsh,hd->bsd", o, layer_attn["wo"].astype(dt)).astype(x.dtype)
 
 
+def rope_tables(cfg: ModelConfig, head_dim: int) -> tuple:
+    """(inverse frequencies or None, cos/sin scale) of the rotary dims."""
+    y = cfg.rope_scaling
+    if y is None:
+        return None, 1.0
+    return (common.yarn_frequencies(head_dim, cfg.rope_theta, y),
+            common.yarn_mscale(y.factor, y.mscale)
+            / common.yarn_mscale(y.factor, y.mscale_all_dim))
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-1/2, times YaRN's mscale(all dims) squared."""
+    m, y = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if y is not None and y.mscale_all_dim:
+        scale *= common.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def mla_attention_block(p: dict, x: jnp.ndarray, cfg: ModelConfig,
+                        positions: jnp.ndarray) -> jnp.ndarray:
+    """Multi-head latent attention (DeepSeek-V2 §2.1), no query LoRA:
+    q = W_q x split into nope and rope dims per head; [c_kv; k_rope] =
+    W_kva x; [k_nope; v] = W_kvb RMSNorm(c_kv); one rotary key shared by
+    all heads; scores over qk_nope + qk_rope dims, values of v_head_dim."""
+    m, h = cfg.mla, cfg.n_heads
+    b, s, _ = x.shape
+    dn, dr, dv, r = (m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim,
+                     m.kv_lora_rank)
+    dt = jnp.dtype(cfg.dtype)
+    xc = x.astype(dt)
+    q = jnp.einsum("bsd,dh->bsh", xc, p["wq"].astype(dt)).reshape(
+        b, s, h, dn + dr)
+    kva = jnp.einsum("bsd,dh->bsh", xc, p["wkv_a"].astype(dt))
+    c_kv = common.rmsnorm(p["kv_norm"], kva[..., :r], cfg.norm_eps)
+    kv = jnp.einsum("bsr,rh->bsh", c_kv, p["wkv_b"].astype(dt)).reshape(
+        b, s, h, dn + dv)
+    freqs, mscale = rope_tables(cfg, dr)
+    q_rope = common.apply_rope(q[..., dn:], positions, cfg.rope_theta,
+                               freqs, mscale)
+    k_rope = common.apply_rope(kva[..., None, r:], positions,
+                               cfg.rope_theta, freqs, mscale)
+    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
+    o = attn_lib.causal_attention(q, k, kv[..., dn:],
+                                  scale=mla_softmax_scale(cfg))
+    o = o.reshape(b, s, h * dv)
+    return jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(dt)).astype(x.dtype)
+
+
 def make_block_fn(cfg: ModelConfig, policy: ShardingPolicy,
-                  window: Optional[int], n_real: Optional[int] = None):
-    """(carry=(x, aux), layer_params) -> ((x, aux), None).
+                  window: Optional[int], n_real: Optional[int] = None,
+                  dense: bool = False):
+    """(carry=(x, aux), layer_params) -> ((x, aux), held).
 
     ``n_real``: number of real (non-pad) positions — pads are masked out
-    of MoE routing so they cannot consume expert capacity."""
+    of MoE routing so they cannot consume expert capacity. ``dense``: the
+    block of a leading dense layer. ``held`` is the number of
+    token-expert pairs the dropless held-expert layer computed, or None.
+    """
 
     seq_par = policy.mesh is not None and policy.seq_axis is not None
+    held_path = cfg.moe is not None and cfg.moe.capacity_factor is None
 
     def block(carry, layer):
         x, aux = carry
@@ -119,23 +202,35 @@ def make_block_fn(cfg: ModelConfig, policy: ShardingPolicy,
         xn = common.rmsnorm(layer["ln1"], x, cfg.norm_eps)
         if seq_par:
             xn = shard_hint(xn, policy, "batch", None, None, force=True)
-        h = attention_block(layer["attn"], xn, cfg, policy, positions,
-                            window)
+        if cfg.mla is not None:
+            h = mla_attention_block(layer["attn"], xn, cfg, positions)
+        else:
+            h = attention_block(layer["attn"], xn, cfg, policy, positions,
+                                window)
         x = x + h
         x = shard_hint(x, policy, "batch", "seq", None)
         hn = common.rmsnorm(layer["ln2"], x, cfg.norm_eps)
         if seq_par:
             hn = shard_hint(hn, policy, "batch", None, None, force=True)
-        if cfg.moe is not None:
-            mask = (jnp.arange(s) < n_real) if n_real is not None else None
-            f, aux_l = moe_ffn(layer["moe"], hn.astype(jnp.dtype(cfg.dtype)),
-                               cfg.moe, policy, mask=mask)
+        dt = jnp.dtype(cfg.dtype)
+        held = None
+        mask = (jnp.arange(s) < n_real) if n_real is not None else None
+        if cfg.moe is not None and not dense and held_path:
+            f, aux_l, held = held_moe_ffn(layer["moe"], hn.astype(dt),
+                                          cfg.moe, mask=mask)
             aux = aux + aux_l
+        elif cfg.moe is not None and not dense:
+            f, aux_l = moe_ffn(layer["moe"], hn.astype(dt), cfg.moe, policy,
+                               mask=mask)
+            aux = aux + aux_l
+        elif dense:
+            ffn = jax.tree.map(lambda w: w.astype(dt), layer["ffn"])
+            f = common.swiglu(ffn, hn.astype(dt))
         else:
-            f = common.swiglu(layer["ffn"], hn.astype(jnp.dtype(cfg.dtype)))
+            f = common.swiglu(layer["ffn"], hn.astype(dt))
         x = x + f.astype(x.dtype)
         x = shard_hint(x, policy, "batch", "seq", None)
-        return (x, aux), None
+        return (x, aux), held
 
     return block
 
@@ -143,14 +238,23 @@ def make_block_fn(cfg: ModelConfig, policy: ShardingPolicy,
 def decoder_forward(params: dict, embeds: jnp.ndarray, cfg: ModelConfig,
                     policy: ShardingPolicy, window: Optional[int],
                     n_real: Optional[int] = None):
-    """Run the layer stack over input embeddings. Returns (x, aux)."""
-    embeds = shard_hint(embeds, policy, "batch", "seq", None)
-    block = make_block_fn(cfg, policy, window, n_real=n_real)
-    if cfg.remat:
-        block = jax.checkpoint(block)
-    (x, aux), _ = jax.lax.scan(block, (embeds, jnp.zeros((), jnp.float32)),
-                               params["layers"])
-    return common.rmsnorm(params["ln_f"], x, cfg.norm_eps), aux
+    """Run the layer stack (the leading dense layers, then the repeated
+    ones) over input embeddings. Returns (x, aux, held pairs or None)."""
+    carry = (shard_hint(embeds, policy, "batch", "seq", None),
+             jnp.zeros((), jnp.float32))
+    held = None
+    for name, dense in (("dense_layers", True), ("layers", False)):
+        if name not in params:
+            continue
+        block = make_block_fn(cfg, policy, window, n_real=n_real,
+                              dense=dense)
+        if cfg.remat:
+            block = jax.checkpoint(block)
+        carry, ys = jax.lax.scan(block, carry, params[name])
+        if ys is not None:
+            held = jnp.sum(ys)
+    x, aux = carry
+    return common.rmsnorm(params["ln_f"], x, cfg.norm_eps), aux, held
 
 
 def logits_fn(params: dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -200,17 +304,22 @@ def make_loss_fn(cfg: ModelConfig, policy: ShardingPolicy,
                  window: Optional[int]):
     def loss_fn(params, batch):
         x, n_prefix, n_pad = embed_inputs(params, batch, cfg)
-        x, aux = decoder_forward(params, x, cfg, policy, window,
-                                 n_real=x.shape[1] - n_pad)
+        x, aux, held = decoder_forward(params, x, cfg, policy, window,
+                                       n_real=x.shape[1] - n_pad)
         s_text = batch["tokens"].shape[1]
         x_text = jax.lax.dynamic_slice_in_dim(x, n_prefix, s_text, axis=1)
         logits = logits_fn(params, x_text, cfg)
         loss = common.softmax_xent(logits, batch["labels"], cfg.vocab_size)
         metrics = {"xent": loss}
         if cfg.moe is not None:
-            aux = aux / cfg.n_layers
+            # the dropless layer's sequence-level loss (DeepSeek) adds
+            # every MoE layer's term; the Switch loss is averaged
+            if cfg.moe.capacity_factor is not None:
+                aux = aux / cfg.n_layers
             metrics["moe_aux"] = aux
             loss = loss + cfg.moe.router_aux_weight * aux
+        if held is not None:
+            metrics["moe_held"] = held
         return loss, metrics
     return loss_fn
 
@@ -382,7 +491,8 @@ def make_spec_rule(cfg: ModelConfig, policy: ShardingPolicy):
     def rule(path: str, shape) -> P:
         if policy.mesh is None:
             return P()
-        stacked = path.startswith(("layers/", "triples/", "tail/"))
+        stacked = path.startswith(("layers/", "dense_layers/", "triples/",
+                                   "tail/"))
         lead = (None,) if stacked else ()
         if cfg.moe is not None:
             ms = moe_spec(path, shape, policy, stacked=stacked)
@@ -444,14 +554,18 @@ def make_state_spec_rule(cfg: ModelConfig, policy: ShardingPolicy):
 def build_decoder_model(cfg: ModelConfig, policy: ShardingPolicy = UNSHARDED,
                         window: Optional[int] = None) -> Model:
     window = window if window is not None else cfg.sliding_window
+    # the dropless held-expert layer: a grouped matmul, built for training
+    held = cfg.moe is not None and cfg.moe.capacity_factor is None
+    serves = cfg.mla is None and not cfg.first_k_dense and not held
     return Model(
         config=cfg,
         policy=policy,
         init=lambda rng: init_decoder_params(rng, cfg),
         loss_fn=make_loss_fn(cfg, policy, window),
-        prefill_fn=make_prefill_fn(cfg, policy, window),
-        decode_fn=make_decode_fn(cfg, policy),
-        init_decode_state=make_init_decode_state(cfg),
+        prefill_fn=make_prefill_fn(cfg, policy, window) if serves else None,
+        decode_fn=make_decode_fn(cfg, policy) if serves else None,
+        init_decode_state=make_init_decode_state(cfg) if serves else None,
         spec_rule=make_spec_rule(cfg, policy),
         state_spec_rule=make_state_spec_rule(cfg, policy),
+        batches_over_clients=not held,
     )

@@ -12,6 +12,8 @@ option of its own.
   its wall seconds, its self seconds (less the part that its child spans
   on the same thread cover) and one to its count.
 - ``count(name, n)`` adds to a counter, on the same gate.
+- ``recording()`` says whether the gate is open, for a caller whose
+  count costs work of its own (a read from the device).
 - ``snapshot()`` returns ``{"seconds", "self_seconds", "count",
   "counters"}``; ``reset()`` clears them.
 
@@ -114,5 +116,6 @@ class Tracer:
 TRACER = Tracer()
 span = TRACER.span
 count = TRACER.count
+recording = _recording
 snapshot = TRACER.snapshot
 reset = TRACER.reset

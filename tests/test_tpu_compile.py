@@ -10,7 +10,9 @@ Compiled at real sizes:
 * the batched round engine's ``local_all`` step for ``paper-mlp-1m8``
   at the ``paper-fig4`` cohort of 10 clients;
 * the sharded float64 pooled TPD evaluator on a mesh of the 4 described
-  devices of one v5e host.
+  devices of one v5e host;
+* DeepSeek-V2-Lite's held-expert layer, forward and backward, at its
+  published widths: the megablox grouped matmul (``gmm``/``tgmm``).
 
 Nothing runs, so nothing here says anything about results or times:
 ``chip_smoke.py`` checks those on the chip. The topology is described
@@ -119,3 +121,42 @@ def test_sharded_pooled_evaluator_compiles_for_v5e_2x2(topo):
     # compiler may turn the psum of disjoint segments into an all-gather)
     assert "all-reduce" in hlo or "all-gather" in hlo
     assert compiled.memory_analysis() is not None
+
+
+def test_held_expert_layer_compiles_for_v5e(one_chip, monkeypatch):
+    """One DeepSeekMoE layer's held experts (8 of 64, top-6 of 4,096
+    tokens, D 2,048, F 1,408) through the grouped matmul kernel and its
+    backward; the layer takes the kernel only where the backend is a
+    TPU, so the test says it is."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.moe import held_experts
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite").moe, n_held=8)
+    t, d, f = 4096, 2048, cfg.d_ff_expert
+    params = {"w_gate": jax.ShapeDtypeStruct((8, d, f), jnp.float32,
+                                             sharding=one_chip),
+              "w_up": jax.ShapeDtypeStruct((8, d, f), jnp.float32,
+                                           sharding=one_chip),
+              "w_down": jax.ShapeDtypeStruct((8, f, d), jnp.float32,
+                                             sharding=one_chip)}
+
+    def loss(p, x, w, i):
+        out, pairs = held_experts(x, w, i, p, cfg)
+        return jnp.sum(out), pairs
+
+    args = (jax.ShapeDtypeStruct((t, d), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((t, cfg.top_k), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((t, cfg.top_k), jnp.int32,
+                                 sharding=one_chip))
+    compiled = jax.jit(jax.grad(loss, has_aux=True)).lower(
+        params, *args).compile()
+    kernels = [line.split(" = ", 1)[0] for line in
+               compiled.as_text().splitlines() if "tpu_custom_call" in line]
+    # the names the trace reduction finds them by (``round.expert_roofline``)
+    assert kernels and all("gmm" in k for k in kernels)
+    assert any("tgmm" in k for k in kernels)
+    # no capacity buffer per expert: the rows are the T x k pairs
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
