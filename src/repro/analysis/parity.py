@@ -136,6 +136,11 @@ REGISTRY: Tuple[OraclePair, ...] = (
         tests=("tests/test_scale_parity.py",),
     ),
     OraclePair(
+        fast="repro.kernels.tpd:leaf_loads",
+        oracle="repro.kernels.ref:leaf_loads_ref",
+        tests=("tests/test_scale_parity.py",),
+    ),
+    OraclePair(
         fast="repro.kernels.fedavg:fedavg_batched_pallas",
         oracle="repro.kernels.ref:fedavg_ref",
         tests=("tests/test_kernels.py",),

@@ -18,8 +18,9 @@ Evaluator tiers (all built from ONE closure, ``_make_batch_tpd``):
   the scalar left-to-right accumulation; at width >= 8 numpy switches to
   unrolled partial sums and agreement drops to ~1e-15 relative).
 * ``batch_tpd`` — whole-swarm (P, D) -> (P,) evaluation; numpy fast path
-  below ``_NP_FASTPATH_ELEMS``; above it the compiled Pallas kernel
-  (``repro.kernels.tpd``) on TPU, jit'd XLA on every other backend.
+  below ``_NP_FASTPATH_ELEMS``; above it, on TPU, one device program
+  that builds the trainer-split leaf loads and runs the Pallas kernel
+  (``repro.kernels.tpd.tpd_scores``), jit'd XLA on every other backend.
 * ``PooledTPDEvaluator`` — S same-shape cost models with independent
   client pools evaluated in ONE exact call (the batched sweep runner's
   engine: placement row i scores against pool ``pool_idx[i]``).
@@ -465,6 +466,7 @@ class CostModel:
         the auto-selection, never an explicit ``backend=``.
         """
         placements = np.asarray(placements, np.int32)
+        tracing.count("tpd.calls")
         if backend is None:
             backend = self.tpd_path(placements.shape[0])
         if backend == "np":
@@ -496,42 +498,30 @@ class CostModel:
         return fn(placements)
 
     def _make_pallas_tpd(self, force_interpret: bool = False):
-        """Closure running the fused Pallas TPD kernel: per call only the
-        (P, L) leaf loads are computed host-side (the trainer-split rank
-        trick) before the kernel fuses the kid-payload sums, eq. 6 and
-        the per-level max-reduce.
+        """Closure running the TPD program (``kernels.tpd.tpd_scores``):
+        the trainer-split leaf loads and the fused Pallas kernel in one
+        jitted call. Per call the host uploads the (P, D) int32
+        placements and reads back the (P,) TPDs; the attribute table is
+        uploaded once, here, and stays on the device.
 
         The kernel is compiled on TPU and interpreted on every other
         backend; ``force_interpret`` interprets it on the TPU too (the
         ``backend="interpret"`` escape hatch).
         """
-        from repro.kernels.tpd import batch_tpd_pallas
+        from repro.kernels.tpd import tpd_scores
         h = self.hierarchy
-        attrs = self._attr_stack(np.float32)        # (3, C)
-        n_leaves, C = h.n_leaves, h.total_clients
+        attrs = jnp.asarray(self._attr_stack(np.float32))   # (3, C)
         interpret = force_interpret or jax.default_backend() != "tpu"
         penalty = float(self.memory_penalty)
 
         def run(placements):
             with tracing.span("tpd.prologue"):
                 placements = np.asarray(placements, np.int32)
-                P = placements.shape[0]
-                p_off = np.arange(P)[:, None]
-                placed = np.bincount((placements + C * p_off).ravel(),
-                                     minlength=P * C).reshape(P, C)
-                unplaced = placed == 0
-                t_mds = np.where(unplaced, attrs[0][None], np.float32(0.0))
-                leaf_of = (np.cumsum(unplaced, axis=1) - 1) % n_leaves
-                leaf_load = np.bincount(
-                    (leaf_of + n_leaves * p_off).ravel(),
-                    weights=t_mds.ravel(),
-                    minlength=P * n_leaves).reshape(P, n_leaves)
             with tracing.span("tpd.transfer"):
-                out = batch_tpd_pallas(
-                    jnp.asarray(placements), jnp.asarray(attrs),
-                    jnp.asarray(leaf_load.astype(np.float32)),
-                    depth=h.depth, width=h.width, penalty=penalty,
-                    interpret=interpret)
+                out = tpd_scores(placements, attrs, depth=h.depth,
+                                 width=h.width, penalty=penalty,
+                                 interpret=interpret)
+            tracing.count("tpd.device_leaf_calls")
             with tracing.span("tpd.wait"):
                 return np.asarray(out)
 

@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Plain oracles for every Pallas kernel in this package (jnp, and numpy
+for the host-side ``leaf_loads_ref``).
 
 These are the correctness references the kernel tests sweep against
 (``assert_allclose`` over shapes x dtypes), and the default compute path
@@ -12,6 +13,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def fedavg_ref(stacked: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
@@ -103,6 +105,28 @@ def tpd_ref(placements, attrs, leaf_load, *, depth: int, width: int,
         total = total + jnp.max(delay[:, start - n:start], axis=1)
         start -= n
     return total
+
+
+def leaf_loads_ref(placements, mds, n_leaves: int) -> np.ndarray:
+    """Host oracle of ``kernels.tpd.leaf_loads``: placements (P, D) int,
+    mds (C,) -> (P, L) float32 trainer loads, by two ``np.bincount``s.
+
+    The placed mask counts each row's ids; the unplaced ids' running
+    count gives each its rank, and rank ``r`` trains under leaf
+    ``r mod L`` (float64 accumulation, rounded to float32 once).
+    """
+    placements = np.asarray(placements)
+    P, C = placements.shape[0], mds.shape[0]
+    p_off = np.arange(P)[:, None]
+    unplaced = np.bincount((placements + C * p_off).ravel(),
+                           minlength=P * C).reshape(P, C) == 0
+    t_mds = np.where(unplaced, np.asarray(mds, np.float32)[None],
+                     np.float32(0.0))
+    leaf_of = (np.cumsum(unplaced, axis=1) - 1) % n_leaves
+    return np.bincount((leaf_of + n_leaves * p_off).ravel(),
+                       weights=t_mds.ravel(),
+                       minlength=P * n_leaves).reshape(
+                           P, n_leaves).astype(np.float32)
 
 
 def fused_adamw_ref(p, g, m, v, lr, bc1, bc2, *, b1=0.9, b2=0.95,

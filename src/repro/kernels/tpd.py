@@ -20,10 +20,12 @@ gather at all:
   level maxima deepest level first;
 * the output is lane-dense, ``(1, P)``.
 
-The trainer-split leaf loads (a rank-among-unplaced scatter) are
-computed host-side by ``CostModel._make_pallas_tpd`` with the same
-bincount trick the numpy evaluator uses, and stream in as a slot-major
-``(L, P)`` operand.
+The trainer-split leaf loads (each unplaced client, ranked in id order,
+trains under leaf ``rank mod L``) are built on the device by
+``leaf_loads`` and stream in as a slot-major ``(L, P)`` operand.
+``tpd_scores`` jits the two together, so a swarm is scored by one
+program that takes the ``(P, D)`` placements and the resident attribute
+table and returns the ``(P,)`` TPDs.
 
 Math accumulates in f32 in the same order as the jnp oracle
 ``kernels.ref.tpd_ref`` (kid payloads summed in kid order, per-level
@@ -121,3 +123,57 @@ def batch_tpd_pallas(placements, attrs, leaf_load, *, depth: int,
         interpret=interpret,
     )(host, leaf)
     return out[0, :P]
+
+
+def leaf_loads(placements, mds, n_leaves: int) -> jnp.ndarray:
+    """placements (P, D) int32, mds (C,) f32 -> (P, L) f32 trainer loads.
+
+    Every client a row does not place is a trainer; ranked in ascending
+    id order, rank ``r`` trains under leaf ``r mod L``, and a leaf's load
+    is the sum of its trainers' ``mds``. A row may repeat an id (legal
+    for the model): it then leaves more trainers.
+
+    Dense, with no scatter or gather over ``P * C``. The placed mask is
+    a ``P * D`` scatter. Trainer ``c`` has rank ``c - s``, where ``s``
+    is the number of placed ids below it, so its payload moves ``s``
+    columns to the left: one binary digit of ``s`` a step, the least
+    significant first. Along a row ``s`` never falls and ``c - s`` rises
+    from trainer to trainer, so no two payloads ever meet. The row,
+    padded to a multiple of ``L`` and folded to ``(P, C / L, L)``, then
+    sums rank ``r`` into column ``r mod L``.
+    """
+    P, D = placements.shape
+    C = mds.shape[0]
+    placed = jnp.zeros((P, C), jnp.int32).at[
+        jnp.arange(P)[:, None], placements].set(1)
+    trainer = placed == 0
+    shift = jnp.where(trainer, jnp.cumsum(placed, axis=1) - placed, 0)
+    payload = jnp.where(trainer, mds.astype(jnp.float32)[None], 0.0)
+    for bit in range(D.bit_length()):      # s <= D distinct placed ids
+        moves = ((shift >> bit) & 1) == 1
+        payload = _move_left(payload, moves, 1 << bit)
+        shift = _move_left(shift, moves, 1 << bit)
+    q = -(-C // n_leaves)
+    payload = jnp.pad(payload, ((0, 0), (0, q * n_leaves - C)))
+    return payload.reshape(P, q, n_leaves).sum(axis=1)
+
+
+def _move_left(x, moves, k: int):
+    """``x`` (P, C) with its ``moves`` entries taken ``k`` columns to the
+    left; the columns they leave read 0."""
+    moved = jnp.pad(jnp.where(moves, x, 0)[:, k:], ((0, 0), (0, k)))
+    return jnp.where(moves, 0, x) + moved
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("depth", "width", "penalty", "interpret"))
+def tpd_scores(placements, attrs, *, depth: int, width: int,
+               penalty: float = 0.0, interpret: bool = False
+               ) -> jnp.ndarray:
+    """placements (P, D) int32, attrs (3, C) f32 -> (P,) f32 TPDs: the
+    device-built leaf loads (``leaf_loads``) and the kernel
+    (``batch_tpd_pallas``) in one program."""
+    leaf = leaf_loads(placements, attrs[0], width ** (depth - 1))
+    return batch_tpd_pallas(placements, attrs, leaf, depth=depth,
+                            width=width, penalty=penalty,
+                            interpret=interpret)

@@ -203,23 +203,41 @@ def test_pooled_evaluator_rejects_mismatched_models():
 # ---------------------------------------------------------------------------
 # Pallas kernel vs its jnp oracle
 # ---------------------------------------------------------------------------
-def _leaf_loads(ps, attrs, C, L):
-    """(P, L) trainer loads per leaf: the host prologue of the Pallas
-    path (rank among unplaced ids, mod leaves)."""
-    P = ps.shape[0]
-    p_off = np.arange(P)[:, None]
-    unplaced = np.bincount((ps + C * p_off).ravel(),
-                           minlength=P * C).reshape(P, C) == 0
-    t_mds = np.where(unplaced, attrs[0][None], np.float32(0.0))
-    leaf_of = (np.cumsum(unplaced, axis=1) - 1) % L
-    return np.bincount((leaf_of + L * p_off).ravel(),
-                       weights=t_mds.ravel(),
-                       minlength=P * L).reshape(P, L).astype(np.float32)
+@pytest.mark.parametrize("case,C,L,P", [
+    ("heterogeneous", 200, 27, 7),
+    ("duplicate ids", 200, 27, 7),
+    ("C not a multiple of L", 1001, 64, 5),
+    ("P not a multiple of 128", 512, 32, 130),
+    ("P = 1", 53, 16, 1),
+])
+def test_device_leaf_loads_match_host_bincount(case, C, L, P):
+    """``kernels.tpd.leaf_loads`` (placed mask, binary-shift compaction,
+    fold by leaf) against its host bincount oracle ``leaf_loads_ref``."""
+    import jax.numpy as jnp
+    from repro.kernels.ref import leaf_loads_ref
+    from repro.kernels.tpd import leaf_loads
+
+    rng = np.random.default_rng(C + P)
+    mds = rng.uniform(1.0, 40.0, C).astype(np.float32)
+    D = min(C // 3, 3 * L)
+    ps = np.stack([rng.permutation(C)[:D] for _ in range(P)]
+                  ).astype(np.int32)
+    if case == "duplicate ids":
+        ps[0, D // 2:] = ps[0, 0]        # half of row 0 repeats one id
+        ps[3, :] = ps[3, 1]              # row 3 places a single client
+    want = leaf_loads_ref(ps, mds, L)
+    got = np.asarray(leaf_loads(jnp.asarray(ps), jnp.asarray(mds), L))
+    assert got.shape == (P, L) and got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    # every unplaced client's payload lands in exactly one leaf
+    for row, loads in zip(ps, got, strict=True):
+        unplaced = np.setdiff1d(np.arange(C), row)
+        assert loads.sum() == pytest.approx(mds[unplaced].sum(), rel=1e-6)
 
 
 def test_pallas_tpd_kernel_matches_oracle_exactly():
     import jax.numpy as jnp
-    from repro.kernels.ref import tpd_ref
+    from repro.kernels.ref import leaf_loads_ref, tpd_ref
     from repro.kernels.tpd import batch_tpd_pallas
 
     h = Hierarchy(depth=4, width=3, trainers_per_leaf=2, n_clients=200)
@@ -230,7 +248,7 @@ def test_pallas_tpd_kernel_matches_oracle_exactly():
     P, C, L = 7, 200, h.n_leaves
     ps = _placements(h, P, seed=2)
     attrs = cm._attr_stack(np.float32)
-    leaf_load = _leaf_loads(ps, attrs, C, L)
+    leaf_load = leaf_loads_ref(ps, attrs[0], L)
     shape = dict(depth=h.depth, width=h.width, penalty=2.5)
     kern = batch_tpd_pallas(jnp.asarray(ps), jnp.asarray(attrs),
                             jnp.asarray(leaf_load), interpret=True, **shape)
@@ -253,7 +271,7 @@ def test_pallas_tpd_lane_tiles_match_tpd_ref(depth, width, n_clients, P,
     128-particle lane tile: pinned exactly against the jnp oracle and
     within f32 tolerance against the float64 scalar model."""
     import jax.numpy as jnp
-    from repro.kernels.ref import tpd_ref
+    from repro.kernels.ref import leaf_loads_ref, tpd_ref
     from repro.kernels.tpd import batch_tpd_pallas
 
     h = Hierarchy(depth=depth, width=width, trainers_per_leaf=2,
@@ -264,7 +282,7 @@ def test_pallas_tpd_lane_tiles_match_tpd_ref(depth, width, n_clients, P,
     cm = CostModel(h, pool, memory_penalty=penalty)
     ps = _placements(h, P, seed=4)
     attrs = cm._attr_stack(np.float32)
-    leaf_load = _leaf_loads(ps, attrs, n_clients, h.n_leaves)
+    leaf_load = leaf_loads_ref(ps, attrs[0], h.n_leaves)
     shape = dict(depth=depth, width=width, penalty=penalty)
     ref = tpd_ref(jnp.asarray(ps), jnp.asarray(attrs),
                   jnp.asarray(leaf_load), **shape)
@@ -397,9 +415,11 @@ def test_batch_tpd_interpret_escape_hatch():
     """backend='interpret' forces the Pallas INTERPRETER on any host:
     the kernel body runs in CI without an accelerator, pinned against
     the scalar model; on non-accelerator backends 'pallas' falls back
-    to the same interpreted build (identical outputs)."""
+    to the same interpreted build (identical outputs). The leaf loads
+    are built in the same program; row 2 repeats an id."""
     h, pool, cm = _scale_setup(n_clients=256, depth=4, width=3)
     ps = _placements(h, 5)
+    ps[2, 5:] = ps[2, 4]
     scalar = np.array([cm.tpd(p) for p in ps])
     got = np.asarray(cm.batch_tpd(ps, backend="interpret"))
     np.testing.assert_allclose(got, scalar, rtol=2e-5)

@@ -6,7 +6,8 @@ Pallas interpreter and the CPU backend cannot: block tilings, gathers
 the TPU lowering refuses, VMEM overruns, programs that do not fit.
 Compiled at real sizes:
 
-* the Pallas TPD kernel at the ``paper-fig3`` and ``large-10k`` shapes;
+* the Pallas TPD kernel at the ``paper-fig3`` and ``large-10k`` shapes,
+  and the scoring program that builds its leaf loads on the device;
 * the batched round engine's ``local_all`` step for ``paper-mlp-1m8``
   at the ``paper-fig4`` cohort of 10 clients;
 * the sharded float64 pooled TPD evaluator on a mesh of the 4 described
@@ -27,7 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core.cost_model import PooledTPDEvaluator
 from repro.experiments import get_scenario
-from repro.kernels.tpd import batch_tpd_pallas
+from repro.kernels.tpd import batch_tpd_pallas, tpd_scores
 from repro.launch.mesh import make_mesh
 
 
@@ -78,6 +79,26 @@ def test_tpd_kernel_compiles_for_v5e(one_chip, scenario, n_particles,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize("n_particles,penalty", [(83, 0.0), (256, 3.0)])
+def test_tpd_scoring_program_compiles_for_v5e(one_chip, n_particles,
+                                              penalty):
+    """The search's scoring program at ``large-10k``: the leaf loads
+    built on the device and the kernel, one program whose only
+    arguments are the placements and the attribute table."""
+    h = get_scenario("large-10k").make_hierarchy()
+    args = (jax.ShapeDtypeStruct((n_particles, h.dimensions), jnp.int32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((3, h.total_clients), jnp.float32,
+                                 sharding=one_chip))
+    compiled = tpd_scores.lower(*args, depth=h.depth, width=h.width,
+                                penalty=penalty, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # no (P, C) operand: not a byte per placement-client pair comes in
+    assert mem.argument_size_in_bytes < n_particles * h.total_clients
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
 
 

@@ -188,6 +188,8 @@ def test_search_opens_every_scoring_span_once_an_iteration(tmp_path):
                        batch_fitness_fn=cm.batch_fitness)
     snap = tracing.snapshot()
     assert snap["count"] == dict.fromkeys(SEARCH_SPANS, 2)
+    # every scored batch built its leaf loads on the device
+    assert snap["counters"] == {"tpd.calls": 2, "tpd.device_leaf_calls": 2}
     own, sec = snap["self_seconds"], snap["seconds"]
     scoring = sec["tpd.prologue"] + sec["tpd.transfer"] + sec["tpd.wait"]
     assert scoring <= sec["search.score"]
@@ -218,7 +220,8 @@ SNAPSHOT = {
                      "tpd.prologue": 0.6, "tpd.transfer": 1.0,
                      "tpd.wait": 0.2},
     "count": {},
-    "counters": {"round.local_calls": 12, "round.indexed_calls": 9},
+    "counters": {"round.local_calls": 12, "round.indexed_calls": 9,
+                 "tpd.calls": 10, "tpd.device_leaf_calls": 8},
 }
 UNITS = 4
 READERS = {
@@ -233,6 +236,7 @@ READERS = {
     "search.prologue_ms": 0.6 / UNITS * 1e3,
     "search.transfer_ms": 1.0 / UNITS * 1e3,
     "search.wait_ms": 0.2 / UNITS * 1e3,
+    "search.device_leaf_share": 8 / 10,
 }
 
 
@@ -269,3 +273,13 @@ def test_indexed_share_is_none_without_the_indexed_counter(monkeypatch):
     monkeypatch.setattr(tracing, "snapshot", lambda: snap)
     assert _reader("round.indexed_share")({"stats": {"units": UNITS}}) \
         is None
+
+
+def test_device_leaf_share_is_zero_when_scoring_stays_on_the_host(
+        monkeypatch):
+    """A program that counts its scoring calls, none of which built the
+    leaf loads on the device (the numpy path), reads a share of 0."""
+    snap = dict(SNAPSHOT, counters={"tpd.calls": 7})
+    monkeypatch.setattr(tracing, "snapshot", lambda: snap)
+    assert _reader("search.device_leaf_share")(
+        {"stats": {"units": UNITS}}) == 0.0
