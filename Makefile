@@ -8,7 +8,7 @@ BENCH_OUT ?= artifacts/benchmarks/BENCH_scale.json
 BENCH_BASELINE ?= benchmarks/baselines/BENCH_scale.baseline.json
 BENCH_TOLERANCE ?= 0.25
 
-.PHONY: verify test lint analyze bench-round bench-fig4 bench-scale \
+.PHONY: verify test lint analyze bench-fig4 bench-scale \
 	bench-scale-smoke bench-baseline experiments-smoke \
 	elastic-emulated-smoke online-smoke faults-smoke calibration-smoke
 
@@ -24,9 +24,6 @@ lint:
 # see src/repro/analysis/__init__.py for the catalog)
 analyze:
 	PYTHONPATH=src $(PY) -m repro.analysis
-
-bench-round:
-	PYTHONPATH=src $(PY) benchmarks/bench_round_engine.py
 
 bench-fig4:
 	PYTHONPATH=src $(PY) benchmarks/bench_fig4_cluster.py --rounds 50

@@ -3,10 +3,11 @@
 Scenario (Sec. IV-C): 10 clients — one beefy (3 cores / 2 GB), two
 medium (1 core / 1 GB), seven tiny (1 core / 64 MB) — train the paper's
 1.8M-param MLP for 50 rounds under three placement strategies: random,
-uniform round-robin, and PSO (Flag-Swap). The TPD per round is MEASURED
-wall time (jax compute scaled by the emulated per-client speed — the
-docker cpu-limit analogue), never model-derived: the optimizer stays
-black-box exactly as deployed.
+uniform round-robin, and PSO (Flag-Swap). The TPD per round is what the
+emulated round charges (each client's local steps and each cluster's
+eq. 6 payload, scaled by the emulated per-client speed — the docker
+cpu-limit analogue): the optimizer only ever sees that number, black-box
+exactly as deployed.
 
 The paper's claims this harness checks:
   * PSO converges around round ~10;
@@ -33,19 +34,10 @@ OUT = Path(__file__).resolve().parent.parent / "artifacts" / "benchmarks"
 
 
 def main(rounds: int = 50, seed: int = 0, n_seeds: int = 1,
-         strategies=("random", "uniform", "pso", "ga", "greedy"),
-         timing: str = "deterministic", engine: str = "auto") -> dict:
-    """``timing='deterministic'`` (default) charges eq.6 unit-work
-    delays through the black-box interface — reproducible anywhere.
-    ``'measured'`` is the docker-faithful wall-clock mode: it needs a
-    QUIET machine (CPU-contended runs drown the 4:1 speed signal in
-    scheduler noise); use n_seeds>1 there, and prefer ``engine='loop'``
-    (per-cluster wall attribution; the batched engine splits level wall
-    time by load share)."""
+         strategies=("random", "uniform", "pso", "ga", "greedy")) -> dict:
     print(f"== Fig. 4: 10-client heterogeneous cluster, {rounds} rounds, "
-          f"{n_seeds} seed(s), timing={timing}, engine={engine} ==")
-    spec = get_scenario("paper-fig4").with_overrides(timing=timing,
-                                                     engine=engine)
+          f"{n_seeds} seed(s) ==")
+    spec = get_scenario("paper-fig4")
     seeds = [seed + 17 * i for i in range(n_seeds)]
     result = run_experiment(spec, list(strategies), rounds=rounds,
                             seeds=seeds)
@@ -97,11 +89,5 @@ if __name__ == "__main__":
     ap.add_argument("--rounds", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seeds", type=int, default=1, dest="n_seeds")
-    ap.add_argument("--measured", action="store_true",
-                    help="wall-clock TPD (docker-faithful; quiet box only)")
-    ap.add_argument("--engine", choices=["auto", "loop", "batched"],
-                    default="auto")
     args = ap.parse_args()
-    main(rounds=args.rounds, seed=args.seed, n_seeds=args.n_seeds,
-         timing="measured" if args.measured else "deterministic",
-         engine=args.engine)
+    main(rounds=args.rounds, seed=args.seed, n_seeds=args.n_seeds)

@@ -23,13 +23,6 @@ ap.add_argument("--rounds", type=int, default=200)
 ap.add_argument("--clients", type=int, default=15)
 ap.add_argument("--strategies", nargs="+",
                 default=["pso", "random", "uniform"])
-ap.add_argument("--engine", choices=["auto", "loop", "batched"],
-                default="auto",
-                help="'batched' (default via auto): one vmap'd jit per "
-                     "round; 'loop': per-client dispatch (seed behavior)")
-ap.add_argument("--measured", action="store_true",
-                help="wall-clock TPD (needs a quiet machine); default is "
-                     "the reproducible deterministic eq.6 timing")
 args = ap.parse_args()
 
 hierarchy = choose_fl_hierarchy(args.clients)
@@ -42,8 +35,7 @@ spec = ScenarioSpec(
     trainers_per_leaf=hierarchy.trainers_per_leaf,
     n_clients=hierarchy.total_clients,
     model="paper-mlp-1m8", local_steps=2, batch_size=32,
-    timing="measured" if args.measured else "deterministic",
-    engine=args.engine, rounds=args.rounds,
+    rounds=args.rounds,
     description="choose_fl_hierarchy-sized emulated MLP training")
 
 result = run_experiment(spec, args.strategies, rounds=args.rounds,

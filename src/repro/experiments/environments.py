@@ -593,7 +593,7 @@ class OnlineEnvironment:
     aggregator slot under the CURRENT placement, where count-or-deadline
     :class:`~repro.online.async_fedavg.AggregatorBuffer`\\ s flush
     partials up the tree, each flush charging the same eq. 6 cluster
-    delay the synchronous engines charge. The round concludes at the
+    delay the synchronous round charges. The round concludes at the
     first ROOT flush: its entries merge into the global model via
     staleness-weighted async FedAvg
     (:func:`~repro.online.async_fedavg.async_merge_batched`), and the
@@ -645,9 +645,6 @@ class OnlineEnvironment:
                  seed: int = 0, faults: Optional[FaultSchedule] = None,
                  retry: Optional[RetryPolicy] = None,
                  quorum_frac: float = 0.0):
-        if orchestrator.engine != "batched":
-            raise ValueError("OnlineEnvironment needs the batched round "
-                             f"engine, got {orchestrator.engine!r}")
         self.orchestrator = orchestrator
         self.clients = orchestrator.clients
         self.cfg = config if config is not None else AsyncConfig()
@@ -1590,8 +1587,7 @@ def build_environment(spec, seed: int = 0, eval_config=None) -> Environment:
     orch = FederatedOrchestrator(
         model, hierarchy, pool, data,
         local_steps=spec.local_steps, batch_size=spec.batch_size,
-        seed=seed, comm_latency=spec.comm_latency, timing=spec.timing,
-        engine=spec.engine)
+        seed=seed, comm_latency=spec.comm_latency)
     if spec.kind == "online":
         async_cfg = AsyncConfig(
             jitter=spec.jitter, staleness_alpha=spec.staleness_alpha,

@@ -421,8 +421,6 @@ class ScenarioSpec:
     local_steps: int = 2
     batch_size: int = 32
     comm_latency: float = 0.0
-    timing: str = "deterministic"
-    engine: str = "auto"
 
     # online-only knobs (see repro.online.async_fedavg.AsyncConfig)
     jitter: float = 0.0                  # lognormal sigma on train delays
@@ -537,7 +535,7 @@ class ScenarioSpec:
         ``for_env('emulated')`` runs a (possibly elastic) simulated
         preset on the Fig. 4 world — real local training via
         ``FederatedOrchestrator``, with the track-specific knobs
-        (``model``, ``local_steps``, ``timing``, ...) taking their
+        (``model``, ``local_steps``, ``batch_size``, ...) taking their
         spec'd values; ``for_env('simulated')`` goes the other way;
         ``for_env('online')`` lifts any preset onto the asynchronous
         event-driven track (with its ``jitter``/``flush_*``/``reopt_*``
@@ -591,6 +589,10 @@ class ScenarioSpec:
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "ScenarioSpec":
         d = dict(d)
+        # artifacts written before the round engine lost its options
+        # still name them
+        d.pop("timing", None)
+        d.pop("engine", None)
         d["pool"] = PoolProfile(**d.get("pool", {}))
         d["events"] = tuple(event_from_dict(e) for e in d.get("events", ()))
         # schema v1/v2 artifacts predate the fault track: absent keys
@@ -710,7 +712,7 @@ register_scenario(ScenarioSpec(
     pool=PoolProfile(kind="explicit", mdatasize=30.0,
                      memcap=_FIG4_MEMCAP, pspeed=_FIG4_PSPEED),
     rounds=50, model="paper-mlp-1m8", local_steps=2, batch_size=32,
-    comm_latency=0.002, timing="deterministic",
+    comm_latency=0.002,
     description="The 10-client heterogeneous docker/MQTT cluster "
                 "(Fig. 4), emulated single-host."))
 
@@ -833,7 +835,7 @@ register_scenario(ScenarioSpec(
     pool=PoolProfile(kind="explicit", mdatasize=30.0,
                      memcap=_FIG4_MEMCAP, pspeed=_FIG4_PSPEED),
     rounds=50, model="paper-mlp-1m8", local_steps=2, batch_size=32,
-    comm_latency=0.002, timing="deterministic",
+    comm_latency=0.002,
     jitter=0.35, staleness_alpha=0.5, flush_fraction=0.75,
     flush_timeout=0.5, server_lr=0.7,
     description="The Fig. 4 cluster asynchronously: jittered arrivals, "
@@ -863,7 +865,7 @@ register_scenario(ScenarioSpec(
     pool=PoolProfile(kind="explicit", mdatasize=30.0,
                      memcap=_FIG4_MEMCAP, pspeed=_FIG4_PSPEED),
     rounds=50, model="paper-mlp-1m8", local_steps=2, batch_size=32,
-    comm_latency=0.002, timing="deterministic",
+    comm_latency=0.002,
     description="paper-fig4's degenerate online twin: zero jitter, "
                 "full-cohort flushes, no deadline — the event queue "
                 "runs but every round is lockstep, bit-identical to "
@@ -875,7 +877,7 @@ register_scenario(ScenarioSpec(
     pool=PoolProfile(kind="explicit", mdatasize=30.0,
                      memcap=_FIG4_MEMCAP, pspeed=_FIG4_PSPEED),
     rounds=50, model="paper-mlp-1m8", local_steps=2, batch_size=32,
-    comm_latency=0.002, timing="deterministic",
+    comm_latency=0.002,
     jitter=0.35, staleness_alpha=0.5, flush_fraction=0.75,
     flush_timeout=0.5, server_lr=0.7,
     fault_profile=FaultProfile(crash_rate=0.15, crash_down_rounds=2,
@@ -899,7 +901,7 @@ register_scenario(ScenarioSpec(
     pool=PoolProfile(kind="explicit", mdatasize=30.0,
                      memcap=_FIG4_MEMCAP, pspeed=_FIG4_PSPEED),
     rounds=40, model="paper-mlp-1m8", local_steps=2, batch_size=32,
-    comm_latency=0.002, timing="deterministic",
+    comm_latency=0.002,
     jitter=0.3, staleness_alpha=0.5, flush_fraction=0.75,
     flush_timeout=0.5, server_lr=0.7,
     events=(StragglerSpike(every=12, duration=3, fraction=0.2,

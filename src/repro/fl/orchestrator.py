@@ -10,34 +10,29 @@ like the physical system would experience them:
 
     TPD = max_c (local train time) + sum_levels max_cluster (agg time)
 
-Heterogeneity: each client's measured compute time is scaled by
+Heterogeneity: each client's charged compute time is scaled by
 1/pspeed_c — the emulation analogue of the paper's docker cpu/memory
 limits. The coordinator never reads pspeed to *decide* anything: the
 strategy only ever sees the final TPD (black-box, as in the paper).
 
-Two round engines drive the same semantics:
+One round engine drives it. Client params ride a leading ``C`` dim:
+local training is ONE jit'd ``vmap``-of-``scan`` per batch-shape bucket
+(``local_all``) and aggregation is ONE jit'd weighted ``segment_sum``
+per tree level, fused into one call (``aggregate_fused``) and driven by
+``Hierarchy.round_plan`` index tables. When the device cannot hold every
+client's training state at once (a client model at published widths),
+the same engine trains the clients in chunks, cluster by cluster down
+the tree, and folds each chunk's weighted updates into one accumulator
+per level as it goes (:meth:`FederatedOrchestrator._round_chunked`); the
+chunk size comes from the model's bytes and the device's memory.
 
-* ``engine='batched'`` (default): client params ride a leading ``C``
-  dim; local training is ONE jit'd ``vmap``-of-``scan`` per round (per
-  batch-shape bucket) and aggregation is ONE jit'd weighted
-  ``segment_sum`` per tree level, driven by ``Hierarchy.round_plan``
-  index tables. This is what scales the emulation past a few dozen
-  clients (benchmarks/bench_round_engine.py sweeps 16 -> 256).
-  When the device cannot hold every client's training state at once (a
-  client model at published widths), the same engine trains the clients
-  in chunks, cluster by cluster down the tree, and folds each chunk's
-  weighted updates into one accumulator per level as it goes
-  (:meth:`FederatedOrchestrator._round_chunked`); the chunk size comes
-  from the model's bytes and the device's memory.
-* ``engine='loop'``: the original per-client / per-cluster dispatch.
-  Its wall-clock timing is per-cluster-faithful (the docker-faithful
-  'measured' mode on a quiet box); the batched engine necessarily
-  *attributes* measured wall time across clients/clusters by load share
-  instead. Deterministic timing is identical between engines.
+Timing is charged, never read off the wall clock: a client's local
+training costs ``local_steps / pspeed`` and a cluster's merge costs the
+eq. 6 payload of its ACTUAL members (see :meth:`_charge_levels`), so a
+round's TPD replays exactly on any machine.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -53,7 +48,6 @@ from repro.fl.aggregation import SegmentAggregator
 from repro.fl.distributed import elastic_rehierarchize
 from repro.models.api import Model
 from repro.utils import tracing
-from repro.utils.trees import tree_weighted_sum
 
 # rng stream tag for elastic data provisioning: joiner shards draw from
 # a dedicated stream so admitting clients never perturbs the training /
@@ -114,15 +108,17 @@ class FederatedOrchestrator:
                  local_lr: float = 0.05, local_steps: int = 4,
                  batch_size: int = 32, time_scale: float = 1.0,
                  comm_latency: float = 0.0, seed: int = 0,
-                 rng_noise: float = 0.0, timing: str = "measured",
-                 engine: str = "auto"):
-        """``timing``: 'measured' uses wall-clock (the docker-faithful
-        mode — requires a quiet machine); 'deterministic' charges eq.6
-        unit-work/pspeed delays through the SAME black-box interface
-        (reproducible on loaded CI boxes). Training math is identical.
-
-        ``engine``: 'batched' (vmap'd clients + segment-sum levels),
-        'loop' (per-client dispatch), or 'auto' (batched)."""
+                 rng_noise: float = 0.0, timing: str = "deterministic",
+                 engine: str = "batched"):
+        """``timing`` and ``engine`` name the one timing rule and the one
+        round engine there are; they stay as keywords for callers that
+        still pass them, and refuse any other value."""
+        if timing != "deterministic":
+            raise ValueError(f"timing={timing!r} was removed: rounds "
+                             "charge eq. 6 delays ('deterministic')")
+        if engine != "batched":
+            raise ValueError(f"engine={engine!r} was removed: rounds run "
+                             "on the batched engine ('batched')")
         assert len(clients) == hierarchy.total_clients == data.n_clients
         self.model = model
         self.hierarchy = hierarchy
@@ -134,26 +130,15 @@ class FederatedOrchestrator:
         self.comm_latency = comm_latency
         self.rng = np.random.default_rng(seed)
         self.rng_noise = rng_noise
-        assert timing in ("measured", "deterministic")
-        self.timing = timing
-        assert engine in ("auto", "loop", "batched")
-        self.engine = "batched" if engine == "auto" else engine
 
         self.params = model.init(jax.random.key(seed))
         self.local_lr = local_lr
-        self._grad_step = jax.jit(jax.value_and_grad(
-            lambda p, b: model.loss_fn(p, b)[0]), static_argnames=())
         self._eval = jax.jit(lambda p, b: model.loss_fn(p, b),
                              static_argnames=())
         self.weights = data.client_weights()
 
-        # weighted-sum of a cluster's updates, jit'd once (loop engine)
-        self._wsum = jax.jit(
-            lambda trees, w: tree_weighted_sum(trees, w),
-            static_argnames=())
-
-        # batched engine state (built lazily in _warmup)
-        self._agg: Optional[SegmentAggregator] = None
+        # engine state; every program is compiled on first use (warmup)
+        self._agg = SegmentAggregator(hierarchy)
         self._local_fns: Dict[bool, Callable] = {}
         self._folds: Optional[tuple] = None
         self._chunk_for: Optional[tuple] = None   # (tree, chunk)
@@ -176,14 +161,14 @@ class FederatedOrchestrator:
         # trace recording (repro.calibration): when enabled, each round
         # captures per-client train times and per-level/per-cluster
         # aggregation delays into ``last_timings``. Recording reads
-        # values the engines already computed — no extra rng draws, no
+        # values the engine already computed — no extra rng draws, no
         # numeric changes — so recording=off runs are byte-identical.
         self.record_timings = False
         self.last_timings: Optional[dict] = None
         self._trace: Optional[dict] = None
 
     # ==================================================================
-    # deterministic per-cluster delay (eq. 6), shared by both engines
+    # deterministic per-cluster delay (eq. 6)
     # ==================================================================
     # eq. 6 payload units / this = charged delay units: puts aggregation
     # in the paper's regime — the 30 MB JSON model on a 64 MB container
@@ -205,96 +190,7 @@ class FederatedOrchestrator:
         return t
 
     # ==================================================================
-    # loop engine (original per-client / per-cluster dispatch)
-    # ==================================================================
-    def _local_train(self, client_id: int, round_idx: int):
-        """Client's local steps. Returns (new_params, loss, measured_time)."""
-        params = self.params
-        t0 = time.perf_counter()
-        loss = 0.0
-        for s in range(self.local_steps):
-            batch = self.data.client_batch(client_id, self.batch_size,
-                                           round_idx * self.local_steps + s)
-            lval, grads = self._grad_step(params, batch)
-            params = jax.tree.map(
-                lambda p, g: p - self.local_lr * g, params, grads)
-            loss = float(lval)
-        jax.block_until_ready(jax.tree.leaves(params)[0])
-        if self.timing == "deterministic":
-            dt = float(self.local_steps)  # unit work per local step
-        else:
-            dt = time.perf_counter() - t0
-        return params, loss, dt / self.clients.pspeed[client_id]
-
-    def _aggregate(self, updates: List, placement: np.ndarray):
-        """Cluster-by-cluster aggregation with per-cluster timing.
-
-        Returns (global_params, total_agg_time) where total_agg_time =
-        sum over levels of the level's max cluster time (eq. 7 semantics,
-        with per-cluster times instead of the model's estimate).
-        """
-        h = self.hierarchy
-        weighted = [jax.tree.map(lambda x, w=w: x * w, u)
-                    for u, w in zip(updates, self.weights, strict=True)]
-        trainers = h.trainer_assignment(placement)
-        slot_value = [None] * h.dimensions
-        mds = self.clients.mdatasize
-        total = 0.0
-        for level in range(h.depth - 1, -1, -1):
-            level_max = 0.0
-            row = None
-            if self._trace is not None:
-                row = {"level": level, "slots": [], "hosts": [],
-                       "loads": [], "n_parts": [], "delays": []}
-            for s in range(h.level_starts[level], h.level_starts[level + 1]):
-                host = int(placement[s])
-                parts = [weighted[host]]
-                members = [host]
-                kids = h.children_slots(s)
-                if kids:
-                    parts.extend(slot_value[k] for k in kids)
-                    members.extend(int(placement[k]) for k in kids)
-                else:
-                    li = s - h.level_starts[h.depth - 1]
-                    parts.extend(weighted[t] for t in trainers[li])
-                    members.extend(trainers[li])
-                t0 = time.perf_counter()
-                acc = self._wsum(parts, [1.0] * len(parts))
-                jax.block_until_ready(jax.tree.leaves(acc)[0])
-                if self.timing == "deterministic":
-                    dt = self._det_cluster_work(members)
-                else:
-                    dt = time.perf_counter() - t0
-                slot_value[s] = acc
-                cluster_t = self._cluster_time(host, dt, len(parts))
-                if row is not None:
-                    row["slots"].append(s)
-                    row["hosts"].append(host)
-                    row["loads"].append(
-                        float(sum(mds[int(c)] for c in members)))
-                    row["n_parts"].append(len(parts))
-                    row["delays"].append(float(cluster_t))
-                level_max = max(level_max, cluster_t)
-            if row is not None:
-                self._trace["levels"].append(row)
-            total += level_max
-        return slot_value[0], total
-
-    def _round_loop(self, r: int, placement: np.ndarray):
-        updates, train_times = [], []
-        for c in range(self.hierarchy.total_clients):
-            p, _, t = self._local_train(c, r)
-            updates.append(p)
-            train_times.append(t)
-        if self._trace is not None:
-            self._trace["train"] = {
-                "clients": list(range(self.hierarchy.total_clients)),
-                "times": [float(t) for t in train_times]}
-        new_params, agg_time = self._aggregate(updates, placement)
-        return new_params, max(train_times), agg_time
-
-    # ==================================================================
-    # batched engine: vmap'd local steps + per-level segment sums
+    # the round engine: vmap'd local steps + per-level segment sums
     # ==================================================================
     def _device_samples(self):
         """The dataset's fixed sample set on the device, as ``(features,
@@ -326,8 +222,8 @@ class FederatedOrchestrator:
         (:meth:`_device_samples`) ``inputs`` is the bucket's int32 table
         of positions into it, (C_bucket, local_steps, batch); otherwise a
         dict of stacked batches, (C_bucket, local_steps, batch, ...).
-        Either way the values are the ones the loop engine feeds step by
-        step. ``ids`` restricts the cohort (the online track trains
+        Either way the values are ``client_batch``'s, step by step.
+        ``ids`` restricts the cohort (the online track trains
         partial cohorts); ``None`` means every client, in id order.
         """
         with tracing.span("round.inputs"):
@@ -417,11 +313,10 @@ class FederatedOrchestrator:
         self._local_fns[indexed] = fn
         return fn
 
-    def _dispatch_local(self, round_idx: int, ids=None):
+    def _dispatch_local(self, round_idx: int, ids):
         """One ``local_all`` call per batch-shape bucket of the cohort
-        (``ids``; ``None`` = every client). Returns ([(bucket client ids,
-        updated params)], wall seconds until the last call is done)."""
-        t0 = time.perf_counter()
+        ``ids``. Returns [(bucket client ids, updated params)], the last
+        call done."""
         pieces: List[Tuple[np.ndarray, object]] = []
         outs = []
         for bucket_ids, inputs in self._collect_batches(round_idx, ids):
@@ -431,7 +326,7 @@ class FederatedOrchestrator:
         with tracing.span("round.wait"):
             jax.block_until_ready(jax.tree.leaves(pieces[-1][1])[0])
         self._count_held(outs)
-        return pieces, time.perf_counter() - t0
+        return pieces
 
     def _local_call(self, inputs):
         """One ``local_all`` dispatch over a bucket (or a chunk of one):
@@ -452,58 +347,14 @@ class FederatedOrchestrator:
             tracing.count("moe.held_assignments",
                           int(sum(np.sum(np.asarray(o[1])) for o in outs)))
 
-    def _train_all_batched(self, round_idx: int):
-        """All clients' local training. Returns (stacked_updates (C,...),
-        train_times (C,))."""
-        C = self.hierarchy.total_clients
-        pieces, wall = self._dispatch_local(round_idx)
-
-        if len(pieces) == 1 and np.array_equal(
-                pieces[0][0], np.arange(C)):
-            stacked_updates = pieces[0][1]
-        else:
-            order = np.concatenate([ids for ids, _ in pieces])
-            perm = jnp.asarray(np.argsort(order))
-            stacked_updates = jax.tree.map(
-                lambda *xs: jnp.concatenate(xs, axis=0)[perm],
-                *[p for _, p in pieces])
-
-        if self.timing == "deterministic":
-            per_client_dt = float(self.local_steps)
-        else:
-            # one fused dispatch: attribute wall time evenly (the loop
-            # engine measures each client; here C clients share the call)
-            per_client_dt = wall / C
-        train_times = per_client_dt / self.clients.pspeed
-        return stacked_updates, train_times
-
     def _agg_batched(self, stacked_updates, placement: np.ndarray):
-        """Per-level segment-sum aggregation + per-cluster timing charge.
-
-        Deterministic timing charges eq. 6 from the plan's ACTUAL member
-        payloads (same formula, same rng stream as the loop engine);
-        measured timing splits each level's wall clock across its
-        clusters by payload share before the pspeed/comm composition.
-        """
+        """The whole aggregation as ONE jit call (``aggregate_fused``),
+        with eq. 6 charged per cluster from the plan (no host syncs)."""
         with tracing.span("round.merge"):
             plan = self.hierarchy.round_plan(placement)
-            if self.timing == "deterministic":
-                # charge eq. 6 analytically; run the whole aggregation as
-                # ONE jit call (no per-level host syncs needed)
-                new_global = self._agg.aggregate_fused(
-                    stacked_updates, self.weights, plan)
-                return new_global, self._charge_levels(plan)
-
-            weighted = self._agg.weighted(stacked_updates, self.weights)
-            walls = []
-            vals = None
-            for idx in range(len(plan.levels)):
-                t0 = time.perf_counter()
-                vals = self._agg.run_level(idx, weighted, vals, plan)
-                jax.block_until_ready(jax.tree.leaves(vals)[0])
-                walls.append(time.perf_counter() - t0)
-            return jax.tree.map(lambda x: x[0], vals), \
-                self._charge_levels(plan, walls)
+            new_global = self._agg.aggregate_fused(
+                stacked_updates, self.weights, plan)
+            return new_global, self._charge_levels(plan)
 
     def _client_chunk(self) -> int:
         """Clients whose local training runs at once. Every client fits
@@ -626,24 +477,19 @@ class FederatedOrchestrator:
         self._count_held(outs)
         return acc[0]
 
-    def _charge_levels(self, plan, walls=None) -> float:
+    def _charge_levels(self, plan) -> float:
         """eq. 7 over the plan's levels: the sum of each level's slowest
-        cluster. Deterministic timing charges eq. 6 from the plan's
-        ACTUAL member payloads (same formula, same rng stream as the
-        loop engine); measured timing splits each level's wall seconds
-        (``walls``) across its clusters by payload share."""
+        cluster, each cluster charged eq. 6 from the plan's ACTUAL member
+        payloads (:meth:`cluster_delay`'s composition)."""
         h = self.hierarchy
         mds = self.clients.mdatasize
         total = 0.0
         for idx, lp in enumerate(plan.levels):
             loads = np.zeros(lp.n_clusters)
             np.add.at(loads, lp.seg, mds[lp.member_clients])
-            if walls is None:
-                cluster_dt = loads / self.EQ6_PAYLOAD_SCALE
-            else:
-                cluster_dt = walls[idx] * loads / max(loads.sum(), 1e-12)
+            cluster_dt = loads / self.EQ6_PAYLOAD_SCALE
             # pspeed/comm/noise composition, vectorized per level (one rng
-            # draw per cluster, same stream order as the loop engine)
+            # draw per cluster, levels deepest first, clusters in order)
             ts = (cluster_dt / self.clients.pspeed[lp.hosts]
                   + self.comm_latency * lp.n_parts)
             if self.rng_noise:
@@ -663,56 +509,47 @@ class FederatedOrchestrator:
         return total
 
     def _round_batched(self, r: int, placement: np.ndarray):
-        if self._agg is None:
-            self._agg = SegmentAggregator(self.hierarchy)
         chunk = self._chunk()
         if chunk < self.hierarchy.total_clients:
-            if self.timing != "deterministic":
-                raise ValueError(
-                    "a round trained in client chunks charges eq. 6 "
-                    "analytically and needs timing='deterministic'")
             new_params = self._round_chunked(r, placement, chunk)
             train_times = float(self.local_steps) / self.clients.pspeed
-            if self._trace is not None:
-                self._trace["train"] = {
-                    "clients": list(range(self.hierarchy.total_clients)),
-                    "times": np.asarray(train_times, np.float64).tolist()}
             with tracing.span("round.merge"):
                 agg_time = self._charge_levels(
                     self.hierarchy.round_plan(placement))
-            return new_params, float(np.max(train_times)), agg_time
-        stacked_updates, train_times = self._train_all_batched(r)
+        else:
+            stacked_updates, train_times = self.train_cohort(None, r)
+            new_params, agg_time = self._agg_batched(stacked_updates,
+                                                     placement)
         if self._trace is not None:
             self._trace["train"] = {
                 "clients": list(range(self.hierarchy.total_clients)),
                 "times": np.asarray(train_times, np.float64).tolist()}
-        new_params, agg_time = self._agg_batched(stacked_updates, placement)
         return new_params, float(np.max(train_times)), agg_time
 
     # ==================================================================
     # partial-cohort hooks (the online track's building blocks)
     # ==================================================================
     def train_cohort(self, ids, round_idx: int):
-        """Local training for a client subset, from the CURRENT global.
+        """Local training for a client subset, from the CURRENT global:
+        one ``local_all`` call per batch-shape bucket.
 
-        ``ids`` must be strictly increasing. Returns ``(stacked_updates,
-        train_times)`` row-aligned to ``ids``. A full-population cohort
-        routes through ``_train_all_batched`` — the exact executable
-        ``run_round`` uses — so a full-cohort call is bit-identical to
-        the synchronous round's training half (the degenerate parity
-        pin rides on this). Partial cohorts share the same per-bucket
-        jit'd fns; only the leading client axis differs.
+        ``ids`` must be strictly increasing; ``None`` means every client
+        (``run_round``'s training half, so a full cohort either way is
+        bit-identical to the synchronous round's). Returns
+        ``(stacked_updates, train_times)`` row-aligned to the cohort,
+        each client charged ``local_steps / pspeed``.
         """
-        ids = np.asarray(ids, np.int64)
-        self._check_population()
-        C = self.hierarchy.total_clients
-        if ids.size and np.any(np.diff(ids) <= 0):
-            raise ValueError("train_cohort ids must be strictly increasing")
-        if ids.size == C:
-            return self._train_all_batched(round_idx)
-        if ids.size == 0:
-            return None, np.zeros(0, np.float64)
-        pieces, wall = self._dispatch_local(round_idx, ids)
+        if ids is None:
+            ids = np.arange(self.hierarchy.total_clients)
+        else:
+            ids = np.asarray(ids, np.int64)
+            self._check_population()
+            if ids.size and np.any(np.diff(ids) <= 0):
+                raise ValueError(
+                    "train_cohort ids must be strictly increasing")
+            if ids.size == 0:
+                return None, np.zeros(0, np.float64)
+        pieces = self._dispatch_local(round_idx, ids)
 
         order = np.concatenate([b for b, _ in pieces])
         if len(pieces) == 1 and np.array_equal(order, ids):
@@ -724,25 +561,18 @@ class FederatedOrchestrator:
             stacked_updates = jax.tree.map(
                 lambda *xs: jnp.concatenate(xs, axis=0)[perm],
                 *[p for _, p in pieces])
-
-        if self.timing == "deterministic":
-            per_client_dt = float(self.local_steps)
-        else:
-            per_client_dt = wall / ids.size
-        train_times = per_client_dt / self.clients.pspeed[ids]
-        return stacked_updates, train_times
+        return stacked_updates, float(self.local_steps) / \
+            self.clients.pspeed[ids]
 
     def aggregate_cohort(self, stacked_updates, placement):
-        """Full-population hierarchical aggregation: the batched
-        engine's fused segment-sum path, returning ``(new_global,
+        """Full-population hierarchical aggregation: the round's
+        fused segment-sum path, returning ``(new_global,
         agg_time)`` WITHOUT committing the params (callers decide —
         the online track's degenerate rounds commit via
         :meth:`set_global`). Bit-identical to ``run_round``'s
         aggregation half."""
         placement = np.asarray(placement, np.int64)
         self.hierarchy.validate_placement(placement)
-        if self._agg is None:
-            self._agg = SegmentAggregator(self.hierarchy)
         return self._agg_batched(stacked_updates, placement)
 
     def cluster_delay(self, host: int, member_clients, n_parts: int
@@ -750,7 +580,7 @@ class FederatedOrchestrator:
         """The eq. 6 delay one aggregation flush charges: payload work
         over the ACTUAL members' model sizes, scaled by the host's
         pspeed plus per-part comm latency — the same composition the
-        synchronous engines charge per cluster, exposed for the online
+        synchronous round charges per cluster, exposed for the online
         track's per-flush timing."""
         dt = self._det_cluster_work(member_clients)
         return self._cluster_time(int(host), dt, int(n_parts))
@@ -798,37 +628,18 @@ class FederatedOrchestrator:
     def warmup(self) -> None:
         """Trace/compile everything once so round-0 timing is not skewed
         by compilation (the docker system has no such artifact)."""
-        if self.engine == "batched":
-            if self._agg is None:
-                self._agg = SegmentAggregator(self.hierarchy)
-            placement = np.arange(self.hierarchy.dimensions)
-            chunk = self._chunk()
-            if chunk < self.hierarchy.total_clients:
-                self._round_chunked(0, placement, chunk)
-                self._evaluate()
-                return
-            stacked, _ = self._train_all_batched(0)
+        placement = np.arange(self.hierarchy.dimensions)
+        chunk = self._chunk()
+        if chunk < self.hierarchy.total_clients:
+            self._round_chunked(0, placement, chunk)
+        else:
+            stacked, _ = self.train_cohort(None, 0)
             noise, self.rng_noise = self.rng_noise, 0.0  # keep rng stream
             try:
                 self._agg_batched(stacked, placement)
             finally:
                 self.rng_noise = noise
-            self._evaluate()
-            return
-        batch = self.data.client_batch(0, self.batch_size, 0)
-        lval, g = self._grad_step(self.params, batch)
-        jax.block_until_ready(lval)
-        h = self.hierarchy
-        n_pool = h.total_clients - h.dimensions
-        base, extra = divmod(n_pool, h.n_leaves)
-        sizes = {h.width + 1, base + 1} | ({base + 2} if extra else set())
-        for k in sorted(sizes):
-            acc = self._wsum([self.params] * k, [1.0] * k)
-            jax.block_until_ready(jax.tree.leaves(acc)[0])
         self._evaluate()
-
-    # kept as an alias for callers of the historical private name
-    _warmup = warmup
 
     # ==================================================================
     # elastic population: admit / retire / sync_population
@@ -876,7 +687,7 @@ class FederatedOrchestrator:
         the FedAvg weights, re-hierarchizes through the SAME
         capacity-window rule (:func:`elastic_rehierarchize`, so both
         tracks replay identical hierarchy sequences for one event
-        schedule), and retargets the batched round engine — the
+        schedule), and retargets the round engine — the
         segment-sum executables are re-jitted only when the tree shape
         actually changed. Events mutating the pool directly
         (``ClientJoin``/``ClientLeave``) are wired here through
@@ -909,8 +720,7 @@ class FederatedOrchestrator:
             slot_remap=slot_remap(old_h, new_h),
             client_remap=client_remap)
         self.hierarchy = new_h
-        if self._agg is not None:
-            self._agg.retarget(new_h)
+        self._agg.retarget(new_h)
         return update
 
     def _check_population(self) -> None:
@@ -947,12 +757,8 @@ class FederatedOrchestrator:
                 self._trace = {"train": {"clients": [], "times": []},
                                "levels": []}
             try:
-                if self.engine == "loop":
-                    new_params, train_time, agg_time = \
-                        self._round_loop(r, placement)
-                else:
-                    new_params, train_time, agg_time = \
-                        self._round_batched(r, placement)
+                new_params, train_time, agg_time = \
+                    self._round_batched(r, placement)
             finally:
                 if self._trace is not None:
                     self._trace["train_time"] = 0.0
@@ -1008,15 +814,6 @@ class FederatedOrchestrator:
             return rec, {"merged": float(C), "degraded_flushes": 0.0,
                          "failovers": 0.0, "dropped_updates": 0.0,
                          "down": 0.0}
-        if self.timing != "deterministic":
-            raise ValueError(
-                "run_round_faulty composes per-cluster delays "
-                "analytically and needs timing='deterministic', got "
-                f"{self.timing!r}")
-        if self.engine != "batched":
-            raise ValueError("run_round_faulty needs the batched round "
-                             f"engine, got {self.engine!r}")
-
         cohort = np.asarray([c for c in range(C) if c not in down],
                             np.int64)
         if cohort.size == 0:
@@ -1091,7 +888,7 @@ class FederatedOrchestrator:
         payloads PRESENT under faults: a leaf cluster charges its
         merged trainers (plus the host's own update if it merged), an
         inner cluster charges its child hosts' forwarded partials.
-        Reduces to the full ``_aggregate`` walk when everything merged."""
+        Reduces to :meth:`_charge_levels` when everything merged."""
         h = self.hierarchy
         trainers = h.trainer_assignment(placement)
         leaf_start = h.level_starts[h.depth - 1]

@@ -19,8 +19,7 @@ from repro.utils import tracing
 def _orch(cfg, h, data, chunk, monkeypatch, **kw):
     orch = FederatedOrchestrator(
         get_model(cfg), h, ClientPool.random(h.total_clients, seed=1), data,
-        local_steps=2, batch_size=kw.pop("batch_size", 16), seed=0,
-        timing="deterministic", engine="batched", **kw)
+        local_steps=2, batch_size=kw.pop("batch_size", 16), seed=0, **kw)
     if chunk is not None:
         monkeypatch.setattr(orch, "_client_chunk", lambda: chunk)
     return orch

@@ -316,8 +316,7 @@ def test_federated_round_matches_reference(ref, lm_cell):
     h = Hierarchy(depth=2, width=2, trainers_per_leaf=2, n_clients=8)
     orch = FederatedOrchestrator(
         model, h, ClientPool.random(8, seed=0), data, local_lr=0.5,
-        local_steps=2, batch_size=1, seed=0, timing="deterministic",
-        engine="batched")
+        local_steps=2, batch_size=1, seed=0)
     orch._client_chunk = lambda: 1
     orch.set_global(lm_cell.init_weights(model, 11))
     init = jax.tree.map(np.asarray, orch.params)

@@ -22,6 +22,7 @@ Covers the PR-5 guarantees:
 import jax
 import numpy as np
 import pytest
+from _round_reference import reference_round
 
 from repro.configs import get_config
 from repro.core.hierarchy import ClientPool, Hierarchy
@@ -36,7 +37,7 @@ from repro.fl.orchestrator import FederatedOrchestrator
 from repro.models import get_model
 
 
-def make_orchestrator(n_clients=10, seed=0, engine="auto", local_steps=2,
+def make_orchestrator(n_clients=10, seed=0, local_steps=2,
                       depth=2, width=2, tpl=1):
     cfg = get_config("mlp-smoke")
     model = get_model(cfg)
@@ -44,8 +45,7 @@ def make_orchestrator(n_clients=10, seed=0, engine="auto", local_steps=2,
     pool = ClientPool.random(n_clients, seed=seed)
     data = FederatedDataset.make(n_clients, seed=seed)
     return FederatedOrchestrator(model, h, pool, data, local_steps=local_steps,
-                                 batch_size=8, seed=seed,
-                                 timing="deterministic", engine=engine)
+                                 batch_size=8, seed=seed)
 
 
 def tree_allclose(a, b):
@@ -57,12 +57,13 @@ def tree_allclose(a, b):
 # admit: joiner initialization + data provisioning
 # ---------------------------------------------------------------------------
 def manual_local_steps(orch, params, client_id, round_idx):
-    """The loop engine's local update recomputed from first principles."""
+    """A client's local update recomputed from first principles."""
+    grad = jax.value_and_grad(orch.model.loss_fn, has_aux=True)
     for s in range(orch.local_steps):
         batch = orch.data.client_batch(
             client_id, orch.batch_size,
             round_idx * orch.local_steps + s)
-        _, grads = orch._grad_step(params, batch)
+        _, grads = grad(params, batch)
         params = jax.tree.map(lambda p, g: p - orch.local_lr * g,
                               params, grads)
     return params
@@ -91,25 +92,37 @@ def test_joiner_first_step_starts_from_current_global_model():
     assert not tree_allclose(got, from_init)       # ...NOT from init
 
     # and the joiner's update is what the next round actually consumes
-    p_new, _, _ = orch._local_train(joiner, 2)
+    stacked, _ = orch.train_cohort([joiner], 2)
+    p_new = jax.tree.map(lambda x: x[0], stacked)
     assert tree_allclose(p_new, from_global)
 
 
 def test_admit_returns_update_and_next_round_runs():
-    for engine in ("batched", "loop"):
-        orch = make_orchestrator(engine=engine)
+    records = {}
+    for engine in ("orchestrator", "reference"):
+        orch = make_orchestrator()
         strat = create_strategy("static", orch.hierarchy, seed=0,
                                 placement=[0, 1, 2])
-        orch.warmup()
-        orch.run_round(0, strat.propose(0))
+        rng = np.random.default_rng(0)
+        if engine == "orchestrator":
+            orch.warmup()
+            step = orch.run_round
+        else:
+            def step(r, p, orch=orch, rng=rng):
+                return reference_round(orch, r, p, rng)
+        step(0, strat.propose(0))
         ids, update = orch.admit(memcap=[20.0, 30.0], pspeed=[7.0, 9.0])
         assert update is not None                  # 12 > capacity 10
         strat.migrate(update)
         p1 = np.asarray(strat.propose(1), np.int64)
         orch.hierarchy.validate_placement(p1)
-        rec1 = orch.run_round(1, p1)
+        rec1 = step(1, p1)
         assert np.isfinite(rec1.tpd) and rec1.tpd > 0
         assert len(rec1.placement) == orch.hierarchy.dimensions
+        records[engine] = rec1
+    a, b = records["orchestrator"], records["reference"]
+    assert a.placement == b.placement
+    assert a.tpd == b.tpd
 
 
 def test_retiring_an_aggregator_host_repairs_next_round():
@@ -233,16 +246,25 @@ def test_segment_aggregator_rejits_only_on_shape_change():
 
 
 def test_batched_and_loop_engines_agree_across_a_resize():
+    """The round engine matches the reference before and after an
+    admission grows the tree."""
     records = {}
-    for engine in ("batched", "loop"):
-        orch = make_orchestrator(engine=engine)
-        orch.warmup()
-        recs = [orch.run_round(0, np.arange(3))]
+    for engine in ("orchestrator", "reference"):
+        orch = make_orchestrator()
+        rng = np.random.default_rng(0)
+        if engine == "orchestrator":
+            orch.warmup()
+            step = orch.run_round
+        else:
+            def step(r, p, orch=orch, rng=rng):
+                return reference_round(orch, r, p, rng)
+        recs = [step(0, np.arange(3))]
         orch.admit(memcap=[20.0, 30.0, 40.0], pspeed=[7.0, 8.0, 9.0])
         dims = orch.hierarchy.dimensions
-        recs.append(orch.run_round(1, np.arange(dims)))
+        recs.append(step(1, np.arange(dims)))
         records[engine] = recs
-    for a, b in zip(records["batched"], records["loop"], strict=True):
+    for a, b in zip(records["orchestrator"], records["reference"],
+                    strict=True):
         assert a.tpd == pytest.approx(b.tpd, rel=1e-5)
         assert a.loss == pytest.approx(b.loss, rel=1e-4)
 

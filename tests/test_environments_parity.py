@@ -95,8 +95,7 @@ def _fresh_orchestrator(cfg, h, seed=0):
     clients = ClientPool.random(h.total_clients, seed=seed)
     data = make_federated_dataset(cfg, h.total_clients, seed=seed)
     return FederatedOrchestrator(model, h, clients, data, local_steps=1,
-                                 batch_size=16, seed=seed,
-                                 timing="deterministic")
+                                 batch_size=16, seed=seed)
 
 
 def test_emulated_env_matches_orchestrator_run(emu_setup):
@@ -144,8 +143,7 @@ def test_fig4_experiment_matches_seed_era_bench(emu_setup):
                                 cost_model=CostModel(h, pool))
         orch = FederatedOrchestrator(model, h, pool, data, local_steps=2,
                                      batch_size=32, seed=0,
-                                     comm_latency=0.002,
-                                     timing="deterministic", engine="auto")
+                                     comm_latency=0.002)
         ref[name] = orch.run(strat, rounds=rounds)
 
     result = run_experiment("paper-fig4", ["pso", "random"],

@@ -266,8 +266,7 @@ def _online_env(async_cfg, seed=0, n_clients=10, depth=2, width=2,
     data = make_federated_dataset(cfg, h.total_clients, seed=seed)
     orch = FederatedOrchestrator(get_model(cfg), h, pool, data,
                                  local_steps=1, batch_size=16, seed=seed,
-                                 comm_latency=0.002,
-                                 timing="deterministic")
+                                 comm_latency=0.002)
     env = OnlineEnvironment(orch, async_cfg, seed=seed)
     env.begin()
     return env
@@ -353,16 +352,18 @@ def test_online_elastic_population_grows_mid_run():
     assert max(run.metrics["topology_version"]) >= 1.0
 
 
-def test_online_env_requires_batched_engine():
+def test_orchestrator_refuses_removed_engine_and_timing():
+    """One round engine, one timing rule: the per-client loop engine and
+    wall-clock timing are refused by name."""
     cfg = get_config("mlp-smoke")
     h = Hierarchy(depth=2, width=2, trainers_per_leaf=1, n_clients=10)
     pool = ClientPool.random(h.total_clients, seed=0)
     data = make_federated_dataset(cfg, h.total_clients, seed=0)
-    orch = FederatedOrchestrator(get_model(cfg), h, pool, data,
-                                 local_steps=1, batch_size=16, seed=0,
-                                 engine="loop")
-    with pytest.raises(ValueError, match="batched"):
-        OnlineEnvironment(orch, AsyncConfig())
+    for kw, name in (({"engine": "loop"}, "'loop'"),
+                     ({"timing": "measured"}, "'measured'")):
+        with pytest.raises(ValueError, match=name):
+            FederatedOrchestrator(get_model(cfg), h, pool, data,
+                                  local_steps=1, batch_size=16, seed=0, **kw)
 
 
 def test_online_strategy_protocol_unmodified():

@@ -1,10 +1,12 @@
-"""The batched round engine vs the sequential loop engine: identical
-training math, identical deterministic TPD, and the eq. 6/7 composition
-contract against the cost model (heterogeneous mdatasize)."""
+"""The round engine vs the plain per-client / per-cluster reference
+(``tests/_round_reference.py``): identical training math, identical
+deterministic TPD, and the eq. 6/7 composition contract against the
+cost model (heterogeneous mdatasize)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _round_reference import reference_round, reference_run
 
 from repro.configs import get_config
 from repro.core.cost_model import CostModel
@@ -26,21 +28,23 @@ def mlp_setup():
     return model, h, clients, data
 
 
-def _run(mlp_setup, engine, rounds=4, **kw):
+def _run(mlp_setup, reference, rounds=4, **kw):
     model, h, clients, data = mlp_setup
     strat = create_strategy("pso", h, seed=0)
     orch = FederatedOrchestrator(model, h, clients, data, local_steps=2,
-                                 batch_size=16, seed=0,
-                                 timing="deterministic", engine=engine, **kw)
+                                 batch_size=16, seed=0, **kw)
+    if reference:
+        return reference_run(orch, strat, rounds, seed=0)
     return orch.run(strat, rounds=rounds)
 
 
 def test_batched_engine_matches_loop_trace(mlp_setup):
-    """The tentpole contract: same per-round loss/accuracy/TPD trace on
-    the paper MLP config (identical training math; fp reassociation in
-    the per-level segment sums is the only permitted delta)."""
-    a = _run(mlp_setup, "loop")
-    b = _run(mlp_setup, "batched")
+    """The round engine's contract: the reference's per-round
+    loss/accuracy/TPD trace on the paper MLP config (identical training
+    math; fp reassociation in the per-level segment sums is the only
+    permitted delta)."""
+    a = _run(mlp_setup, reference=True)
+    b = _run(mlp_setup, reference=False)
     for ra, rb in zip(a.rounds, b.rounds, strict=True):
         assert ra.placement == rb.placement
         assert ra.tpd == rb.tpd                 # deterministic: exact
@@ -50,18 +54,21 @@ def test_batched_engine_matches_loop_trace(mlp_setup):
 
 def test_engines_agree_with_noise_and_comm(mlp_setup):
     """rng stream parity: per-cluster noise draws must line up exactly."""
-    a = _run(mlp_setup, "loop", rounds=3, rng_noise=0.05, comm_latency=0.01)
-    b = _run(mlp_setup, "batched", rounds=3, rng_noise=0.05,
+    a = _run(mlp_setup, reference=True, rounds=3, rng_noise=0.05,
+             comm_latency=0.01)
+    b = _run(mlp_setup, reference=False, rounds=3, rng_noise=0.05,
              comm_latency=0.01)
     np.testing.assert_array_equal(a.tpds, b.tpds)
 
 
-@pytest.mark.parametrize("engine", ["loop", "batched"])
-def test_deterministic_tpd_composes_cost_model(engine):
+@pytest.mark.parametrize("run", ["reference", "orchestrator", "defaults"])
+def test_deterministic_tpd_composes_cost_model(run):
     """Regression for the child-payload bug (charged mdatasize[0] for
-    every child): with heterogeneous mdatasize, the orchestrator's
-    deterministic agg time must equal the CostModel eq. 6/7 composition
-    (scaled by the /10 emulation factor), for BOTH engines."""
+    every child): with heterogeneous mdatasize, the round's agg time
+    must equal the CostModel eq. 6/7 composition (scaled by the /10
+    emulation factor), for the reference and the orchestrator, and for
+    an orchestrator built with every default (whose timing is the eq. 6
+    charge, never the wall clock)."""
     cfg = get_config("paper-mlp-1m8")
     model = get_model(cfg)
     h = Hierarchy(depth=2, width=2, trainers_per_leaf=2, n_clients=12)
@@ -70,15 +77,22 @@ def test_deterministic_tpd_composes_cost_model(engine):
     clients.mdatasize = rng.uniform(1.0, 40.0, h.total_clients)
     data = make_federated_dataset(cfg, h.total_clients, seed=3)
     placement = rng.permutation(h.total_clients)[: h.dimensions]
-    orch = FederatedOrchestrator(model, h, clients, data, local_steps=1,
-                                 batch_size=8, seed=3,
-                                 timing="deterministic", engine=engine)
     strat = create_strategy("static", h, placement=placement)
-    res = orch.run(strat, rounds=1)
+    if run == "defaults":
+        orch = FederatedOrchestrator(model, h, clients, data)
+        steps = 4
+    else:
+        orch = FederatedOrchestrator(model, h, clients, data,
+                                     local_steps=1, batch_size=8, seed=3)
+        steps = 1
+    if run == "reference":
+        res = reference_run(orch, strat, 1, seed=3)
+    else:
+        res = orch.run(strat, rounds=1)
     r = res.rounds[0]
     cm = CostModel(h, clients)
     assert r.agg_time == pytest.approx(cm.tpd(placement) / 10.0, rel=1e-9)
-    assert r.train_time == pytest.approx(1.0 / clients.pspeed.min())
+    assert r.train_time == pytest.approx(steps / clients.pspeed.min())
     assert r.tpd == pytest.approx(r.train_time + r.agg_time)
 
 
@@ -174,8 +188,7 @@ def _indexed_pair(n_clients=6, local_steps=2, batch_size=16):
         orch = FederatedOrchestrator(
             model, h, ClientPool.random(h.total_clients, seed=4),
             _HostStacked(data) if wrap else data, local_steps=local_steps,
-            batch_size=batch_size, seed=4, timing="deterministic",
-            engine="batched")
+            batch_size=batch_size, seed=4)
         orch.warmup()
         return orch
 
@@ -214,10 +227,17 @@ def test_indexed_path_trains_like_the_host_stacked_path():
         assert table.shape[:2] == (len(ids), indexed.local_steps)
     assert all(isinstance(b, dict) for _, b in stacked._collect_batches(0))
     placement = np.arange(indexed.hierarchy.dimensions)
+    # the two buckets' updates come back in client order: the reference
+    # trains the same clients one by one
+    ref, _ = _indexed_pair()
+    rng = np.random.default_rng(4)
     for r in range(3):
         a = indexed.run_round(r, placement)
         b = stacked.run_round(r, placement)
+        c = reference_round(ref, r, placement, rng)
         assert (a.tpd, a.loss, a.accuracy) == (b.tpd, b.loss, b.accuracy)
+        assert (a.tpd, a.accuracy) == (c.tpd, c.accuracy)
+        assert abs(a.loss - c.loss) < 5e-6
     _assert_same_params(indexed.params, stacked.params)
     # each orchestrator built only its own variant of ``local_all``
     assert list(indexed._local_fns) == [True]
@@ -281,8 +301,7 @@ def test_indexed_calls_counter(family, tmp_path):
                                       seq_len=8)
         orch = FederatedOrchestrator(
             get_model(cfg), h, ClientPool.random(h.total_clients, seed=1),
-            data, local_steps=1, batch_size=2, seed=1,
-            timing="deterministic", engine="batched")
+            data, local_steps=1, batch_size=2, seed=1)
         orch.warmup()
         assert orch._device_samples() is None
     tracing.reset()

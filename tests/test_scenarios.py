@@ -72,6 +72,10 @@ def test_spec_dict_round_trip():
     back = ScenarioSpec.from_dict(d)
     assert back == spec
     assert back.to_dict() == d
+    # an artifact written while the spec still named the round engine's
+    # timing and engine reads back as the same spec
+    assert ScenarioSpec.from_dict(
+        {**d, "timing": "deterministic", "engine": "auto"}) == spec
 
 
 def test_pool_profile_validation():

@@ -129,8 +129,7 @@ def emulated():
         data.partitions[0] = data.partitions[0][:5]
         orch = FederatedOrchestrator(
             model, h, ClientPool.random(h.total_clients, seed=0), data,
-            local_steps=2, batch_size=16, seed=0, timing="deterministic",
-            engine="batched")
+            local_steps=2, batch_size=16, seed=0)
         orch.warmup()
         return orch, data
 
